@@ -38,11 +38,11 @@ cache = cache.prefill(k, k)                        # prompt quantized once
 new = jax.random.normal(jax.random.PRNGKey(2), (B, Hkv, 1, D))
 cache = cache.append(new, new)                     # streaming decode token
 
-# one-token attention directly on the int8 cache (Pallas kernel on TPU)
+# one-token attention directly on the int8 cache: the compiled Pallas
+# kernel on a TPU, its XLA twin elsewhere
 qv = jax.random.normal(jax.random.PRNGKey(3), (B, H, D))
 out = ops.quant_attention_decode(qv, cache.k_q, cache.k_s, cache.v_q,
-                                 cache.v_s, cache.length,
-                                 impl="pallas_interpret")
+                                 cache.v_s, cache.length)
 print(f"fused decode attention out: {out.shape}, "
       f"cache bytes {cache.memory_bytes/2**20:.2f} MiB "
       f"(bf16 would be {2*B*Hkv*ML*D*2/2**20:.2f} MiB, "

@@ -9,8 +9,8 @@ physical pages, so capacity is bounded by *actual* tokens (DESIGN.md §5).
 Two device pytrees plus one host-side policy object:
 
 ``PagePool`` — the physical storage + allocator state (DESIGN.md §5):
-    k_q, v_q    int8  (n_pages, page_size, H_kv, D)
-    k_s, v_s    f32   (n_pages, H_kv, D)    one scale row per page
+    k_q, v_q    int8  (n_pages, H_kv, page_size, D)   heads-major pages
+    k_s, v_s    f32   (n_pages, H_kv, 1, D)   one scale row per page+head
     free_stack  int32 (n_pages,)            free page ids; top = n_free-1
     n_free      int32 ()
 
@@ -29,6 +29,9 @@ between steps; nothing on the device ever sees a refcount.
 Key invariants:
   * page_size == quantization block size: one scale row per page, so scales
     stream with their page through the fused kernel (DESIGN.md §5).
+  * Pages are heads-major: a (row, head) kernel step reads one
+    (page_size, D) tile and one (1, D) scale row, whose last two dims are
+    the array's own — the tiling Mosaic requires on the TPU.
   * Page 0 is a reserved sentinel: it is never allocated, unmapped table
     entries point at it, and masked-out rows scatter into it. Its contents
     are garbage by design and always masked out of attention by `length`.
@@ -538,12 +541,13 @@ def scatter_to_pool(k_q, k_s, v_q, v_s):
     nb = k_s.shape[2]
     ps = T // nb
 
-    def q2p(x):             # (B, H, T, D) -> (B*nb, ps, H, D)
-        return x.reshape(B, H, nb, ps, D).transpose(0, 2, 3, 1, 4).reshape(
-            B * nb, ps, H, D)
+    def q2p(x):             # (B, H, T, D) -> (B*nb, H, ps, D)
+        return x.reshape(B, H, nb, ps, D).transpose(0, 2, 1, 3, 4).reshape(
+            B * nb, H, ps, D)
 
-    def s2p(s):             # (B, H, nb, D) -> (B*nb, H, D)
-        return s.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(B * nb, H, D)
+    def s2p(s):             # (B, H, nb, D) -> (B*nb, H, 1, D)
+        return s.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
+            B * nb, H, 1, D)
 
     pad = lambda a: jnp.concatenate([jnp.zeros_like(a[:1]), a], axis=0)
     table = (1 + jnp.arange(B * nb, dtype=jnp.int32)).reshape(B, nb)
@@ -552,18 +556,18 @@ def scatter_to_pool(k_q, k_s, v_q, v_s):
 
 def gather_pages(pool_kq, pool_ks, pool_vq, pool_vs, page_table):
     """Materialize the contiguous cache layout from a page pool:
-    pool int8 (n_pages, ps, H, D) + table (B, NT) -> int8 (B, H, NT*ps, D)
+    pool int8 (n_pages, H, ps, D) + table (B, NT) -> int8 (B, H, NT*ps, D)
     + f32 scales (B, H, NT, D). Reference path — the fused kernel gathers
     pages via its index_map instead (DESIGN.md §5)."""
     B, NT = page_table.shape
-    _, ps, H, D = pool_kq.shape
+    _, H, ps, D = pool_kq.shape
 
     def gq(pool_q):
-        g = pool_q[page_table]                       # (B, NT, ps, H, D)
-        return g.transpose(0, 3, 1, 2, 4).reshape(B, H, NT * ps, D)
+        g = pool_q[page_table]                       # (B, NT, H, ps, D)
+        return g.transpose(0, 2, 1, 3, 4).reshape(B, H, NT * ps, D)
 
-    def gs(pool_s):
-        return pool_s[page_table].transpose(0, 2, 1, 3)   # (B, H, NT, D)
+    def gs(pool_s):                                  # (B, NT, H, 1, D)
+        return pool_s[page_table][:, :, :, 0].transpose(0, 2, 1, 3)
 
     return gq(pool_kq), gs(pool_ks), gq(pool_vq), gs(pool_vs)
 
@@ -585,18 +589,19 @@ def page_bytes_for(page_size: int, kv_heads: int, head_dim: int,
 @dataclasses.dataclass
 class PagePool:
     """Shared physical page storage + functional free-list allocator
-    (DESIGN.md §5): k_q/v_q (n_pages, tokens_packed, H_kv, D) in the pool's
+    (DESIGN.md §5): k_q/v_q (n_pages, H_kv, tokens_packed, D) in the pool's
     ``kv_dtype`` storage (int8 / fp8_e4m3 / int4-packed-in-int8 — DESIGN.md
     §9; tokens_packed is page_size, or page_size // 2 for int4), k_s/v_s f32
-    (n_pages, H_kv, D) — one scale row per page, identical across backends —
+    (n_pages, H_kv, 1, D) — one scale row per page, identical across
+    backends —
     plus an int32 free stack. ``kv_dtype`` is a *meta* field: it is part of
     the pytree structure, so jitted functions retrace (never reuse a stale
     trace) when a pool of a different precision flows in. Device-side
     pytree; allocation *policy* (refcounts, prefix caching) lives in the
     host-side `HostPageAllocator` (DESIGN.md §7)."""
-    k_q: jax.Array          # kv storage (n_pages, tokens_packed, H_kv, D)
+    k_q: jax.Array          # kv storage (n_pages, H_kv, tokens_packed, D)
     v_q: jax.Array
-    k_s: jax.Array          # f32  (n_pages, H_kv, D)
+    k_s: jax.Array          # f32  (n_pages, H_kv, 1, D)
     v_s: jax.Array
     free_stack: jax.Array   # int32 (n_pages,); entries [0, n_free) are free
     n_free: jax.Array       # int32 ()
@@ -611,9 +616,10 @@ class PagePool:
         if page_size % 8:
             raise ValueError(f"page_size must be a multiple of 8, got {page_size}")
         ps_eff = Q.packed_tokens(page_size, kv_dtype)
-        zq = jnp.zeros((n_pages, ps_eff, kv_heads, head_dim),
+        zq = jnp.zeros((n_pages, kv_heads, ps_eff, head_dim),
                        Q.kv_storage_dtype(kv_dtype))
-        zs = jnp.full((n_pages, kv_heads, head_dim), Q._EPS, jnp.float32)
+        zs = jnp.full((n_pages, kv_heads, 1, head_dim), Q._EPS,
+                      jnp.float32)
         # pages 1..n_pages-1 are allocatable; slot for the sentinel is unused
         stack = jnp.roll(jnp.arange(n_pages, dtype=jnp.int32), -1)
         return PagePool(zq, jnp.zeros_like(zq), zs, jnp.copy(zs), stack,
@@ -664,7 +670,7 @@ class PagePool:
     def tokens_packed(self) -> int:
         """Storage rows per page along the token axis (page_size, or
         page_size // 2 for the int4 backend — DESIGN.md §9)."""
-        return self.k_q.shape[1]
+        return self.k_q.shape[2]
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -763,14 +769,14 @@ class PagedQuantizedKVCache:
         flat_ids = ids.reshape(-1)                 # (B*nb,)
 
         def to_pages(x_q):
-            # (B, H, nb*ps_eff, D) -> (B*nb, ps_eff, H, D)
-            xb = x_q.reshape(B, H, nb, ps_eff, D).transpose(0, 2, 3, 1, 4)
-            return xb.reshape(B * nb, ps_eff, H, D)
+            # (B, H, nb*ps_eff, D) -> (B*nb, H, ps_eff, D)
+            xb = x_q.reshape(B, H, nb, ps_eff, D).transpose(0, 2, 1, 3, 4)
+            return xb.reshape(B * nb, H, ps_eff, D)
 
         def scales_to_pages(s):
-            # (B, H, nb, D) -> (B*nb, H, D)
+            # (B, H, nb, D) -> (B*nb, H, 1, D)
             return s.astype(jnp.float32).transpose(0, 2, 1, 3).reshape(
-                B * nb, H, D)
+                B * nb, H, 1, D)
 
         return dataclasses.replace(
             self.pool,
@@ -922,12 +928,14 @@ class PagedQuantizedKVCache:
         fq_v, fs_v = Q.quantize_page_matrix(resid_v, kv_dtype)
         pid = self.page_table[jnp.arange(B), blk]   # (B,)
         pid = jnp.where(full, pid, SENTINEL_PAGE)   # non-flushing -> sentinel
+        # the residual is already heads-major: a flushed page lands as is
+        srow = lambda s: s.astype(jnp.float32)[:, :, None]   # (B, H, 1, D)
         pool = dataclasses.replace(
             self.pool,
-            k_q=self.pool.k_q.at[pid].set(fq_k.transpose(0, 2, 1, 3)),
-            v_q=self.pool.v_q.at[pid].set(fq_v.transpose(0, 2, 1, 3)),
-            k_s=self.pool.k_s.at[pid].set(fs_k.astype(jnp.float32)),
-            v_s=self.pool.v_s.at[pid].set(fs_v.astype(jnp.float32)))
+            k_q=self.pool.k_q.at[pid].set(fq_k),
+            v_q=self.pool.v_q.at[pid].set(fq_v),
+            k_s=self.pool.k_s.at[pid].set(srow(fs_k)),
+            v_s=self.pool.v_s.at[pid].set(srow(fs_v)))
         clear = full[:, None, None, None]
         advance = 1 if row_mask is None else row_mask.astype(jnp.int32)
         return dataclasses.replace(

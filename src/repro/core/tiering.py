@@ -291,8 +291,8 @@ class SwapCostModel:
 def repack_page(q, s, src_dtype: str, dst_dtype: str):
     """Requantize ONE page's values+scales between storage dtypes
     (DESIGN.md §11): pool layout in, pool layout out — values
-    ``(..., tokens_packed, H, D)`` with their per-page-channel f32 scales
-    ``(..., H, D)``. ``src == dst`` is the verbatim fast path (bitwise).
+    ``(..., H, tokens_packed, D)`` with their per-page-channel f32 scales
+    ``(..., H, 1, D)``. ``src == dst`` is the verbatim fast path (bitwise).
     Otherwise the page is dequantized and requantized through
     `Q.quantize_page_matrix`, so a demote+promote round trip through a
     cheaper host dtype costs at most the sum of both dtypes' analytic
@@ -300,10 +300,7 @@ def repack_page(q, s, src_dtype: str, dst_dtype: str):
     test in tests/test_tiering.py. Returns host numpy ``(q, s)``."""
     if src_dtype == dst_dtype:
         return np.asarray(q), np.asarray(s)
-    # pool layout packs tokens on axis -3; the quantizers speak (..., T, D)
-    qt = jnp.moveaxis(jnp.asarray(q), -3, -2)          # (..., H, tp, D)
-    st = jnp.asarray(s)[..., None, :]                  # (..., H, 1, D)
-    x = Q.dequantize_pages(qt, st, src_dtype)          # (..., H, ps, D)
+    # heads-major pages are already the quantizers' (..., T, D) layout
+    x = Q.dequantize_pages(jnp.asarray(q), jnp.asarray(s), src_dtype)
     q2, s2 = Q.quantize_page_matrix(x, dst_dtype)      # (..., H, tp2, D)
-    return (np.asarray(jnp.moveaxis(q2, -2, -3)),      # (..., tp2, H, D)
-            np.asarray(s2))
+    return np.asarray(q2), np.asarray(s2)[..., None, :]

@@ -92,7 +92,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
                   kv_offset: int = 0, block_q: int = 256, block_k: int = 256,
-                  skip_dead: bool = True, interpret: bool = True):
+                  skip_dead: bool = True, interpret: bool):
     """Batched flash forward: q (B, H, S, D); k/v (B, Hkv, T, D) ->
     (B, H, S, D) f32. GQA via vmap over (B, Hkv), G folded into q rows.
 

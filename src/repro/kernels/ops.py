@@ -1,15 +1,16 @@
 """Public jit'd kernel API with backend dispatch.
 
-On TPU the Pallas kernels compile natively; on this CPU container they run
-under interpret=True (numerically identical, Python-speed). The model /
-serving layers call through here with ``impl="auto"`` which resolves to:
+The model and serving layers call through here with ``impl="auto"``, and
+`resolve_impl` is the one place that picks the implementation:
 
-    * "pallas"  on TPU backends
-    * "xla"     on CPU (pure-jnp reference path; what the dry-run lowers)
+    * "pallas"  on a TPU backend — the compiled Mosaic kernels;
+    * "xla"     on any other backend — the pure-jnp twins, kept as CPU
+      oracles (what the tests and the CPU dry run lower).
 
-so the multi-pod dry-run lowers clean XLA HLO while the kernels stay
-drop-in for real hardware. ``impl="pallas_interpret"`` forces interpreted
-Pallas (used by tests/benchmarks to exercise the kernel bodies).
+``impl="pallas_interpret"`` runs the Pallas kernel bodies in the
+interpreter; tests and benchmarks ask for it by name to exercise the
+kernels on the CPU. It is refused on a TPU, so a run on the chip never
+executes a kernel in interpret mode.
 """
 from __future__ import annotations
 
@@ -41,9 +42,17 @@ def _unpack_int4_axis(q: jax.Array, axis: int) -> jax.Array:
 
 
 def resolve_impl(impl: Impl = "auto") -> str:
-    if impl != "auto":
-        return impl
-    return "pallas" if jax.default_backend() == "tpu" else "xla"
+    """"auto" -> "pallas" on a TPU backend, "xla" elsewhere. An explicit
+    "pallas_interpret" on a TPU backend raises."""
+    on_tpu = jax.default_backend() == "tpu"
+    if impl == "auto":
+        return "pallas" if on_tpu else "xla"
+    if impl not in ("xla", "pallas", "pallas_interpret"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if impl == "pallas_interpret" and on_tpu:
+        raise ValueError("impl='pallas_interpret' on a TPU backend: the "
+                         "chip runs the compiled kernels only")
+    return impl
 
 
 # -- quantization ------------------------------------------------------------
@@ -118,9 +127,9 @@ def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
                                     impl: Impl = "auto"):
     """Flash partials over a quantized page pool through per-row page tables.
 
-    q (B, H, D); pool_kq/vq (P, ps_packed, Hkv, D) in ``kv_dtype`` storage
+    q (B, H, D); pool_kq/vq (P, Hkv, ps_packed, D) in ``kv_dtype`` storage
     (int8 / fp8_e4m3 / int4-packed, where int4 packs two tokens per byte so
-    ps_packed = ps // 2 — DESIGN.md §9); pool_ks/vs (P, Hkv, D) f32;
+    ps_packed = ps // 2 — DESIGN.md §9); pool_ks/vs (P, Hkv, 1, D) f32;
     page_table (B, NT) int32; lengths (B,) int32 — per-row valid tokens
     (pass the flushed prefix count; the residual tail merges separately).
     Lengths also bound each row's page walk: the kernel never streams pages
@@ -160,8 +169,8 @@ def paged_attention_prefill(q, k, v, pool_kq, pool_ks, pool_vq, pool_vs,
     """Fused varlen chunk-prefill attention over the quantized page pool.
 
     q (B, H, C, D) chunk queries; k/v (B, Hkv, C, D) the chunk's own fp
-    K/V; pool_kq/vq (P, ps_packed, Hkv, D) in ``kv_dtype`` storage
-    (int8 / fp8_e4m3 / int4-packed); pool_ks/vs (P, Hkv, D) f32;
+    K/V; pool_kq/vq (P, Hkv, ps_packed, D) in ``kv_dtype`` storage
+    (int8 / fp8_e4m3 / int4-packed); pool_ks/vs (P, Hkv, 1, D) f32;
     page_table (B, NT) int32; hist_len (B,) int32 per-row resident history
     (page-aligned); valid (B,) int32 per-row true chunk tokens (None = C).
     `hist_blocks` (static) bounds the history walk to the dispatch group's
@@ -190,7 +199,7 @@ def _hist_partials(qg, pool_kq, pool_ks, pool_vq, pool_vs, kv_dtype, tbl,
                    hist_len):
     """Flash partials (o, s, m) of chunk queries over `tbl`'s history pages.
 
-    Pages keep their native (nb, ps, Hkv, D) layout — dequant multiplies
+    Pages keep their native (nb, Hkv, ps, D) layout — dequant multiplies
     the per-page scale row in place and the einsums contract it directly
     (no (B, H, T, D) transpose/reshape). Masking is an additive bias folded
     into the logits BEFORE exp, and there is no post-exp mask multiply: a
@@ -198,16 +207,15 @@ def _hist_partials(qg, pool_kq, pool_ks, pool_vq, pool_vs, kv_dtype, tbl,
     has any live position (m finite), and a fully-masked row (cursor 0
     inside a deep-history dispatch) keeps m == -1e30 so the caller's merge
     weight exp(m - mx) zeroes its entire contribution."""
-    kq, vq = pool_kq[tbl], pool_vq[tbl]                # (B, nb, ps_eff, Hkv, D)
+    kq, vq = pool_kq[tbl], pool_vq[tbl]          # (B, nb, Hkv, ps_eff, D)
     if kv_dtype == "int4":
-        kq = _unpack_int4_axis(kq, 2)                  # token axis is 2 here
-        vq = _unpack_int4_axis(vq, 2)
+        kq = _unpack_int4_axis(kq, 3)                  # token axis is 3 here
+        vq = _unpack_int4_axis(vq, 3)
     kh = kq.astype(jnp.float32) * \
-        pool_ks[tbl][:, :, None].astype(jnp.float32)   # (B, nb, ps, Hkv, D)
-    vh = vq.astype(jnp.float32) * \
-        pool_vs[tbl][:, :, None].astype(jnp.float32)
-    nb, ps = kh.shape[1], kh.shape[2]
-    lh = jnp.einsum("bhgcd,bnphd->bhgcnp", qg, kh)
+        pool_ks[tbl].astype(jnp.float32)               # (B, nb, Hkv, ps, D)
+    vh = vq.astype(jnp.float32) * pool_vs[tbl].astype(jnp.float32)
+    nb, ps = kh.shape[1], kh.shape[3]
+    lh = jnp.einsum("bhgcd,bnhpd->bhgcnp", qg, kh)
     pos = (jnp.arange(nb, dtype=jnp.int32)[:, None] * ps +
            jnp.arange(ps, dtype=jnp.int32)[None])             # (nb, ps)
     mh = pos[None] < jnp.asarray(hist_len, jnp.int32)[:, None, None]
@@ -216,7 +224,7 @@ def _hist_partials(qg, pool_kq, pool_ks, pool_vq, pool_vs, kv_dtype, tbl,
     mxh = jnp.max(lh, axis=(-2, -1), keepdims=True)
     ph = jnp.exp(lh - mxh)
     sh = jnp.sum(ph, axis=(-2, -1))[..., None]
-    oh = jnp.einsum("bhgcnp,bnphd->bhgcd", ph, vh)
+    oh = jnp.einsum("bhgcnp,bnhpd->bhgcd", ph, vh)
     return oh, sh, mxh[..., 0]                                # (..., c, 1)
 
 
@@ -256,7 +264,7 @@ def _prefill_fused_xla(q, k, v, pool_kq, pool_ks, pool_vq, pool_vs,
     if hist_blocks == 0:
         out = oc / jnp.maximum(sc, 1e-30)
         return out.reshape(B, H, C, D)
-    ps = pool_kq.shape[1] * (2 if kv_dtype == "int4" else 1)  # logical tokens
+    ps = pool_kq.shape[2] * (2 if kv_dtype == "int4" else 1)  # logical tokens
     hist_len = jnp.asarray(hist_len, jnp.int32)
     # dead-block skip, XLA edition: pick the smallest ladder rung covering
     # ceil(max(hist_len) / ps) and run the history partials at that static

@@ -111,7 +111,8 @@ def _attn_update(q, k, v, pos0, n_slots, length, window, max_len,
 
 def _flat_decode_kernel(len_ref, win_ref, q_ref, kq_ref, ks_ref, vq_ref,
                         vs_ref, o_ref, m_ref, l_ref,
-                        m_scr, l_scr, acc_scr, *, block_t: int, max_len: int):
+                        m_scr, l_scr, acc_scr, *, block_t: int, max_len: int,
+                        per_block: bool):
     b = pl.program_id(0)
     t = pl.program_id(2)
     nt = pl.num_programs(2)
@@ -128,9 +129,14 @@ def _flat_decode_kernel(len_ref, win_ref, q_ref, kq_ref, ks_ref, vq_ref,
 
     @pl.when(t * block_t < n_slots)      # dead block: DMA clamped + no compute
     def _step():
+        # the row's scale rows sit whole in VMEM: pick this block's row
+        # (per-block) or the single per-channel row
+        row = pl.ds(t, 1) if per_block else pl.ds(0, 1)
         # dequantize K/V tiles in VMEM (int8 -> f32 multiply by scale row)
-        k = kq_ref[0, 0].astype(jnp.float32) * ks_ref[0, 0].astype(jnp.float32)
-        v = vq_ref[0, 0].astype(jnp.float32) * vs_ref[0, 0].astype(jnp.float32)
+        k = (kq_ref[0, 0].astype(jnp.float32) *
+             ks_ref[0, 0, row, :].astype(jnp.float32))
+        v = (vq_ref[0, 0].astype(jnp.float32) *
+             vs_ref[0, 0, row, :].astype(jnp.float32))
         _attn_update(q_ref[0, 0].astype(jnp.float32), k, v, t * block_t,
                      n_slots, length, window, max_len, m_scr, l_scr, acc_scr)
 
@@ -146,7 +152,7 @@ def _flat_decode_kernel(len_ref, win_ref, q_ref, kq_ref, ks_ref, vq_ref,
 @functools.partial(jax.jit,
                    static_argnames=("block_t", "skip_dead", "interpret"))
 def _decode_flat(qg, k_q, k_s, v_q, v_s, lengths, windows, *, block_t: int,
-                 skip_dead: bool = True, interpret: bool = True):
+                 skip_dead: bool, interpret: bool):
     """qg (B, Hkv, Gp, D); k_q/v_q (B, Hkv, T, D) int8; k_s/v_s
     (B, Hkv, nb, D) f32; lengths/windows (B,) int32.
     Returns (o (B, Hkv, Gp, D), m (B, Hkv, Gp, 1), l (B, Hkv, Gp, 1))."""
@@ -161,17 +167,15 @@ def _decode_flat(qg, k_q, k_s, v_q, v_s, lengths, windows, *, block_t: int,
         t_idx = lambda t, ln: _dead_clamp(t, ln, block_t, T)
     else:
         t_idx = lambda t, ln: t
-    # scale-row index for a token block: per-block (nb == nt) streams one
-    # scale row with its block (clamped identically); per-channel (nb == 1)
-    # pins row 0.
-    if nb == 1:
-        s_idx = lambda t, ln: 0
-    elif nb == nt:
-        s_idx = t_idx
-    else:
+    # scale rows: per-block (nb == nt) or per-channel (nb == 1). A row's
+    # whole (nb, D) scale slab is one block — its last two dims are the
+    # array's, so Mosaic takes it at any nb — fetched once per (row, head);
+    # the kernel picks the block's row in VMEM.
+    if nb not in (1, nt):
         raise ValueError(f"scale rows {nb} incompatible with {nt} token blocks")
 
-    kernel = functools.partial(_flat_decode_kernel, block_t=block_t, max_len=T)
+    kernel = functools.partial(_flat_decode_kernel, block_t=block_t, max_len=T,
+                               per_block=nb > 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,               # per-row lengths + windows (SMEM)
         grid=(B, Hkv, nt),                   # token blocks innermost
@@ -179,12 +183,10 @@ def _decode_flat(qg, k_q, k_s, v_q, v_s, lengths, windows, *, block_t: int,
             pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, ln, wn: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_t, D),
                          lambda b, h, t, ln, wn: (b, h, t_idx(t, ln[b]), 0)),
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, t, ln, wn: (b, h, s_idx(t, ln[b]), 0)),
+            pl.BlockSpec((1, 1, nb, D), lambda b, h, t, ln, wn: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, block_t, D),
                          lambda b, h, t, ln, wn: (b, h, t_idx(t, ln[b]), 0)),
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, t, ln, wn: (b, h, s_idx(t, ln[b]), 0)),
+            pl.BlockSpec((1, 1, nb, D), lambda b, h, t, ln, wn: (b, h, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, ln, wn: (b, h, 0, 0)),
@@ -225,9 +227,9 @@ def _default_block_t(T, nb):
 
 
 def quant_attention_decode_partials(q, k_q, k_s, v_q, v_s, length, *,
-                                    window=None, block_t: int | None = None,
-                                    skip_dead: bool = True,
-                                    interpret: bool = True):
+                                    interpret: bool, window=None,
+                                    block_t: int | None = None,
+                                    skip_dead: bool = True):
     """Batched fused decode partials: q (B, H, D) over int8 cache
     (B, Hkv, T, D) — ONE pallas_call over a (B, Hkv, NT) grid (no Python or
     vmap fan-out). `length` () or (B,): per-row valid tokens; blocks beyond a
@@ -250,9 +252,9 @@ def quant_attention_decode_partials(q, k_q, k_s, v_q, v_s, length, *,
     return trim(o), trim(m), trim(l)
 
 
-def quant_attention_decode(q, k_q, k_s, v_q, v_s, length, *, window=None,
-                           block_t: int | None = None, skip_dead: bool = True,
-                           interpret: bool = True):
+def quant_attention_decode(q, k_q, k_s, v_q, v_s, length, *, interpret: bool,
+                           window=None, block_t: int | None = None,
+                           skip_dead: bool = True):
     """Normalized fused decode attention: (B, H, D) f32."""
     o, m, l = quant_attention_decode_partials(
         q, k_q, k_s, v_q, v_s, length, window=window, block_t=block_t,
@@ -300,7 +302,7 @@ def _decode_kernel(len_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
 def _decode_single(q, k_q, k_s, v_q, v_s, length, window, *, block_t: int,
-                   interpret: bool = True):
+                   interpret: bool):
     G, D = q.shape
     T = k_q.shape[0]
     nb = k_s.shape[0]
@@ -342,9 +344,8 @@ def _decode_single(q, k_q, k_s, v_q, v_s, length, window, *, block_t: int,
 
 
 def quant_attention_decode_partials_vmap(q, k_q, k_s, v_q, v_s, length, *,
-                                         window=None,
-                                         block_t: int | None = None,
-                                         interpret: bool = True):
+                                         interpret: bool, window=None,
+                                         block_t: int | None = None):
     """SEED BASELINE (benchmarks only): one kernel launch per (batch ×
     kv-head) via nested vmap. See module docstring for why the flat grid
     replaced it."""
@@ -386,8 +387,10 @@ def page_dequant(q_tile, scale_row, kv_dtype: str, page_size: int):
     f32. Plain jnp ops, so the same code serves Pallas kernel bodies and
     the XLA twins."""
     if kv_dtype == "int4":
-        lo = (q_tile << 4) >> 4          # sign-extend low nibble (arith shift)
-        hi = q_tile >> 4                 # arithmetic shift keeps sign
+        # widen before shifting: Mosaic has no shifts on int8 vectors
+        x = q_tile.astype(jnp.int32)     # sign-extends the packed byte
+        lo = (x << 28) >> 28             # sign-extend low nibble (arith shift)
+        hi = x >> 4                      # arithmetic shift keeps sign
         q_tile = jnp.stack([lo, hi], axis=1).reshape(page_size,
                                                      q_tile.shape[-1])
     return q_tile.astype(jnp.float32) * scale_row.astype(jnp.float32)
@@ -412,8 +415,8 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, kq_ref, ks_ref, vq_ref,
 
     @pl.when(t * page_size < length)     # dead page: DMA clamped + no compute
     def _step():
-        k = page_dequant(kq_ref[0, :, 0, :], ks_ref[0], kv_dtype, page_size)
-        v = page_dequant(vq_ref[0, :, 0, :], vs_ref[0], kv_dtype, page_size)
+        k = page_dequant(kq_ref[0, 0], ks_ref[0, 0], kv_dtype, page_size)
+        v = page_dequant(vq_ref[0, 0], vs_ref[0, 0], kv_dtype, page_size)
         _attn_update(q_ref[0, 0].astype(jnp.float32), k, v, t * page_size,
                      length, length, max_len, max_len, m_scr, l_scr, acc_scr)
 
@@ -427,14 +430,14 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, kq_ref, ks_ref, vq_ref,
 @functools.partial(jax.jit, static_argnames=("skip_dead", "interpret",
                                              "kv_dtype"))
 def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
-                  lengths, *, skip_dead: bool = True, interpret: bool = True,
-                  kv_dtype: str = "int8"):
-    """qg (B, Hkv, Gp, D); pool_* (P, ps_packed, Hkv, D) in the pool's
+                  lengths, *, skip_dead: bool, interpret: bool,
+                  kv_dtype: str):
+    """qg (B, Hkv, Gp, D); pool_* (P, Hkv, ps_packed, D) in the pool's
     storage dtype (int8 / fp8_e4m3 / int4-packed: ps_packed = ps // 2) /
-    (P, Hkv, D) f32 scales; page_table (B, NT) int32; lengths (B,) int32.
+    (P, Hkv, 1, D) f32 scales; page_table (B, NT) int32; lengths (B,) int32.
     Returns (o (B, Hkv, Gp, D), m (B, Hkv, Gp, 1), l (B, Hkv, Gp, 1))."""
     B, Hkv, Gp, D = qg.shape
-    _, ps_eff, _, _ = pool_kq.shape      # packed token rows per page
+    ps_eff = pool_kq.shape[2]            # packed token rows per page
     ps = 2 * ps_eff if kv_dtype == "int4" else ps_eff   # logical tokens
     NT = page_table.shape[1]
     if skip_dead:
@@ -444,6 +447,7 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
         t_idx = lambda t, ln: _dead_clamp(t, ln, ps, NT * ps)
     else:
         t_idx = lambda t, ln: t
+    page = lambda b, h, t, pt, ln: (pt[b, t_idx(t, ln[b])], h, 0, 0)
     kernel = functools.partial(_paged_decode_kernel, page_size=ps,
                                kv_dtype=kv_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -451,19 +455,13 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
         grid=(B, Hkv, NT),
         in_specs=[
             pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, pt, ln: (b, h, 0, 0)),
-            # physical page gather: logical block t of row b -> pt[b, t]
-            pl.BlockSpec((1, ps_eff, 1, D),
-                         lambda b, h, t, pt, ln:
-                         (pt[b, t_idx(t, ln[b])], 0, h, 0)),
-            pl.BlockSpec((1, 1, D),
-                         lambda b, h, t, pt, ln:
-                         (pt[b, t_idx(t, ln[b])], h, 0)),
-            pl.BlockSpec((1, ps_eff, 1, D),
-                         lambda b, h, t, pt, ln:
-                         (pt[b, t_idx(t, ln[b])], 0, h, 0)),
-            pl.BlockSpec((1, 1, D),
-                         lambda b, h, t, pt, ln:
-                         (pt[b, t_idx(t, ln[b])], h, 0)),
+            # physical page gather: logical block t of row b -> pt[b, t];
+            # heads-major pages make each block's last two dims the
+            # array's own (ps_packed, D) and (1, D), as Mosaic requires
+            pl.BlockSpec((1, 1, ps_eff, D), page),
+            pl.BlockSpec((1, 1, 1, D), page),
+            pl.BlockSpec((1, 1, ps_eff, D), page),
+            pl.BlockSpec((1, 1, 1, D), page),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, pt, ln: (b, h, 0, 0)),
@@ -488,19 +486,18 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
 
 
 def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
-                                    page_table, lengths, *,
+                                    page_table, lengths, *, interpret: bool,
                                     skip_dead: bool = True,
-                                    interpret: bool = True,
                                     kv_dtype: str = "int8"):
     """Batched paged decode partials: q (B, H, D) over a page pool
-    (P, ps_packed, Hkv, D) in ``kv_dtype`` storage (int8 / fp8_e4m3 /
+    (P, Hkv, ps_packed, D) in ``kv_dtype`` storage (int8 / fp8_e4m3 /
     int4-packed — DESIGN.md §9) through per-row page tables (B, NT).
     `lengths` (B,) masks each row independently (pass the *flushed* prefix
     count; the fp residual tail is merged by the caller) and bounds each
     row's page walk (`skip_dead`). Returns (o_unnormalized (B, H, D),
     m (B, H, 1), l (B, H, 1))."""
     B, H, D = q.shape
-    Hkv = pool_kq.shape[2]
+    Hkv = pool_kq.shape[1]
     qg, G = _group_queries(q, Hkv)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     o, m, l = _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs,

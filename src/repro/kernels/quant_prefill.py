@@ -46,6 +46,46 @@ from repro.kernels.quant_attention import _dead_clamp, page_dequant
 
 _NEG_INF = -1e30
 
+# Scoped VMEM the kernel asks Mosaic for. A v5e core has 128 MiB of VMEM;
+# the compiler's default scope (16 MiB) is what the (G*C, D) query, output
+# and flash-state blocks outgrow first.
+PREFILL_VMEM_LIMIT = 64 * 2**20
+
+
+def _key_tile(chunk: int, page_size: int) -> int:
+    """Keys per inner step of the chunk walk: one page's worth."""
+    return page_size if chunk % page_size == 0 else chunk
+
+
+def prefill_vmem_bytes(group: int, chunk: int, page_size: int,
+                       head_dim: int) -> int:
+    """Estimated VMEM of one dispatch: G*C query rows, each a lane-padded
+    f32 row in the double-buffered query and output blocks and the flash
+    state (acc, m, l), plus the double-buffered chunk K/V blocks and the
+    f32 temporaries of one (G*C, key_tile) logit tile."""
+    rows, lanes = group * chunk, -(-head_dim // 128) * 128
+    kt = _key_tile(chunk, page_size)
+    row_bytes = 4 * (2 * 2 * lanes + lanes + 2 * 128 + 4 * kt)
+    return rows * row_bytes + 4 * (2 * 2 * chunk * lanes
+                                   + 4 * page_size * lanes)
+
+
+def check_prefill_fits(group: int, chunk: int, page_size: int,
+                       head_dim: int) -> None:
+    """Refuse a chunk width the kernel cannot hold in VMEM: the footprint
+    grows with G*C, so the largest chunk shrinks as the GQA group grows."""
+    need = prefill_vmem_bytes(group, chunk, page_size, head_dim)
+    if need > PREFILL_VMEM_LIMIT:
+        fit = page_size
+        while prefill_vmem_bytes(group, 2 * fit, page_size,
+                                 head_dim) <= PREFILL_VMEM_LIMIT:
+            fit *= 2
+        raise ValueError(
+            f"prefill chunk of {chunk} tokens needs ~{need / 2**20:.0f} MiB "
+            f"of VMEM for {group} query heads per KV head (limit "
+            f"{PREFILL_VMEM_LIMIT / 2**20:.0f} MiB); use a prefill chunk of "
+            f"at most {fit} tokens")
+
 
 def _update(logits, mask, v, m_scr, l_scr, acc_scr):
     """Online-softmax accumulate of one masked (GC, bt) logit tile."""
@@ -63,7 +103,7 @@ def _update(logits, mask, v, m_scr, l_scr, acc_scr):
 def _prefill_kernel(pt_ref, hl_ref, vd_ref, q_ref, kc_ref, vc_ref,
                     kq_ref, ks_ref, vq_ref, vs_ref, o_ref,
                     m_scr, l_scr, acc_scr, *, page_size: int, chunk: int,
-                    kv_dtype: str):
+                    key_tile: int, kv_dtype: str):
     b = pl.program_id(0)
     t = pl.program_id(2)
     nt = pl.num_programs(2)          # NT history steps + 1 chunk step
@@ -81,8 +121,8 @@ def _prefill_kernel(pt_ref, hl_ref, vd_ref, q_ref, kc_ref, vc_ref,
     # (int8 / fp8 cast, int4 nibble-unpack — DESIGN.md §9)
     @pl.when(jnp.logical_and(t < nt - 1, t * page_size < hist_len))
     def _hist():                     # dead page: DMA clamped + no compute
-        k = page_dequant(kq_ref[0, :, 0, :], ks_ref[0], kv_dtype, page_size)
-        v = page_dequant(vq_ref[0, :, 0, :], vs_ref[0], kv_dtype, page_size)
+        k = page_dequant(kq_ref[0, 0], ks_ref[0, 0], kv_dtype, page_size)
+        v = page_dequant(vq_ref[0, 0], vs_ref[0, 0], kv_dtype, page_size)
         logits = jax.lax.dot_general(            # (GC, ps)
             q_ref[0, 0], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -91,19 +131,27 @@ def _prefill_kernel(pt_ref, hl_ref, vd_ref, q_ref, kc_ref, vc_ref,
         _update(logits, pos < hist_len, v, m_scr, l_scr, acc_scr)
 
     # -- chunk step: the chunk's own fp K/V, causal + valid masked ---------
+    # Walked in (key_tile, D) key tiles, so the live logit tile is
+    # (GC, key_tile) rather than (GC, C): VMEM grows linearly in C.
     @pl.when(t == nt - 1)
     def _chunk():
-        k = kc_ref[0, 0]                         # (C, D) f32
-        v = vc_ref[0, 0]
-        logits = jax.lax.dot_general(            # (GC, C)
-            q_ref[0, 0], k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # query row r is chunk position r % C of its head-group lane
-        qpos = jax.lax.rem(
-            jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0), chunk)
-        kpos = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        _update(logits, (kpos <= qpos) & (kpos < valid), v,
-                m_scr, l_scr, acc_scr)
+        def tile(j, carry):
+            start = pl.multiple_of(j * key_tile, key_tile)
+            k = kc_ref[0, 0, pl.ds(start, key_tile), :]      # (kt, D) f32
+            v = vc_ref[0, 0, pl.ds(start, key_tile), :]
+            logits = jax.lax.dot_general(                    # (GC, kt)
+                q_ref[0, 0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            # query row r is chunk position r % C of its head-group lane
+            qpos = jax.lax.rem(
+                jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0), chunk)
+            kpos = start + jax.lax.broadcasted_iota(jnp.int32,
+                                                    logits.shape, 1)
+            _update(logits, (kpos <= qpos) & (kpos < valid), v,
+                    m_scr, l_scr, acc_scr)
+            return carry
+
+        jax.lax.fori_loop(0, chunk // key_tile, tile, 0)
         o_ref[0, 0] = (acc_scr[...] /
                        jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
@@ -112,17 +160,17 @@ def _prefill_kernel(pt_ref, hl_ref, vd_ref, q_ref, kc_ref, vc_ref,
                                              "interpret", "kv_dtype"))
 def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
                    page_table, hist_len, valid, *, hist_blocks: int,
-                   skip_dead: bool = True, interpret: bool = True,
-                   kv_dtype: str = "int8"):
+                   skip_dead: bool, interpret: bool, kv_dtype: str):
     """qg (B, Hkv, G*C, D) f32 pre-scaled queries; kc/vc (B, Hkv, C, D) f32
-    chunk K/V; pool_* (P, ps_packed, Hkv, D) in ``kv_dtype`` storage
-    (int4: ps_packed = ps // 2) / (P, Hkv, D) f32 scales; page_table
+    chunk K/V; pool_* (P, Hkv, ps_packed, D) in ``kv_dtype`` storage
+    (int4: ps_packed = ps // 2) / (P, Hkv, 1, D) f32 scales; page_table
     (B, >=max(hist_blocks, 1)) int32; hist_len/valid (B,) int32.
     Returns normalized (B, Hkv, G*C, D) f32."""
     B, Hkv, GC, D = qg.shape
     C = kc.shape[2]
-    _, ps_eff, _, _ = pool_kq.shape      # packed token rows per page
+    ps_eff = pool_kq.shape[2]            # packed token rows per page
     ps = 2 * ps_eff if kv_dtype == "int4" else ps_eff   # logical tokens
+    check_prefill_fits(GC // C, C, ps, D)
     NT = hist_blocks
     pt = page_table[:, :max(NT, 1)]
     if skip_dead:
@@ -133,8 +181,9 @@ def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
     # pipeline issues no DMA for the unused pool tiles on the final step
     p_idx = lambda t, ln: t_idx(jnp.minimum(t, max(NT - 1, 0)), ln)
 
+    page = lambda b, h, t, pt, hl, vd: (pt[b, p_idx(t, hl[b])], h, 0, 0)
     kernel = functools.partial(_prefill_kernel, page_size=ps, chunk=C,
-                               kv_dtype=kv_dtype)
+                               key_tile=_key_tile(C, ps), kv_dtype=kv_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,       # page table + hist lens + valids (SMEM)
         grid=(B, Hkv, NT + 1),
@@ -146,18 +195,11 @@ def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
             pl.BlockSpec((1, 1, C, D),
                          lambda b, h, t, pt, hl, vd: (b, h, 0, 0)),
             # physical page gather: logical history block t -> pt[b, t]
-            pl.BlockSpec((1, ps_eff, 1, D),
-                         lambda b, h, t, pt, hl, vd:
-                         (pt[b, p_idx(t, hl[b])], 0, h, 0)),
-            pl.BlockSpec((1, 1, D),
-                         lambda b, h, t, pt, hl, vd:
-                         (pt[b, p_idx(t, hl[b])], h, 0)),
-            pl.BlockSpec((1, ps_eff, 1, D),
-                         lambda b, h, t, pt, hl, vd:
-                         (pt[b, p_idx(t, hl[b])], 0, h, 0)),
-            pl.BlockSpec((1, 1, D),
-                         lambda b, h, t, pt, hl, vd:
-                         (pt[b, p_idx(t, hl[b])], h, 0)),
+            # (heads-major pages: (ps_packed, D) and (1, D) tiles)
+            pl.BlockSpec((1, 1, ps_eff, D), page),
+            pl.BlockSpec((1, 1, 1, D), page),
+            pl.BlockSpec((1, 1, ps_eff, D), page),
+            pl.BlockSpec((1, 1, 1, D), page),
         ],
         out_specs=pl.BlockSpec((1, 1, GC, D),
                                lambda b, h, t, pt, hl, vd: (b, h, 0, 0)),
@@ -171,6 +213,8 @@ def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hkv, GC, D), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=PREFILL_VMEM_LIMIT),
         interpret=interpret,
     )(pt.astype(jnp.int32), hist_len.astype(jnp.int32),
       valid.astype(jnp.int32), qg, kc, vc,
@@ -179,13 +223,13 @@ def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
 
 def paged_attention_prefill(q, k, v, pool_kq, pool_ks, pool_vq, pool_vs,
                             page_table, hist_len, valid=None, *,
-                            hist_blocks: int, skip_dead: bool = True,
-                            interpret: bool = True, kv_dtype: str = "int8"):
+                            hist_blocks: int, interpret: bool,
+                            skip_dead: bool = True, kv_dtype: str = "int8"):
     """Fused varlen chunk-prefill attention over a quantized page pool.
 
     q (B, H, C, D) chunk queries; k/v (B, Hkv, C, D) the chunk's own fp
-    K/V; pool_* (P, ps_packed, Hkv, D) in ``kv_dtype`` storage (int8 /
-    fp8_e4m3 / int4-packed — DESIGN.md §9) / (P, Hkv, D) f32 scales;
+    K/V; pool_* (P, Hkv, ps_packed, D) in ``kv_dtype`` storage (int8 /
+    fp8_e4m3 / int4-packed — DESIGN.md §9) / (P, Hkv, 1, D) f32 scales;
     page_table (B, NT) int32; hist_len (B,) int32 resident history tokens
     per row (page-aligned); valid (B,) int32 true chunk tokens per row
     (None = C). `hist_blocks` (static) bounds the history walk — ONE

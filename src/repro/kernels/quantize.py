@@ -13,8 +13,11 @@ TPU adaptation of the paper's CUDA kernel family (DESIGN.md §2):
   over T, so a grid-revisited accumulator pass runs first, then a quantize
   pass — still 2 reads + 1 write, matching the paper's traffic.
 
-All kernels run under interpret=True on CPU for validation; compiled lowering
-targets TPU.
+Every entry point takes ``interpret`` explicitly: True runs the kernel body
+in the Pallas interpreter (CPU validation), False compiles it with Mosaic.
+These standalone kernels do not compile for a TPU yet: their (1, bd) scale
+blocks are not tile-aligned. Only the benchmarks call them; the serving
+path quantizes inside the cache update.
 """
 from __future__ import annotations
 
@@ -91,7 +94,7 @@ def _pick_block_t(T: int) -> int:
 @functools.partial(jax.jit, static_argnames=("block_t", "block_d", "interpret"))
 def quantize_per_channel(x: jax.Array, *, block_t: int | None = None,
                          block_d: int | None = None,
-                         interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                         interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Paper-faithful whole-matrix per-channel quantization of (T, D).
 
     Returns (int8 (T, D), f32 scales (D,)).
@@ -126,7 +129,7 @@ def quantize_per_channel(x: jax.Array, *, block_t: int | None = None,
 @functools.partial(jax.jit, static_argnames=("block_size", "block_d", "interpret"))
 def quantize_blocked(x: jax.Array, block_size: int = 256, *,
                      block_d: int | None = None,
-                     interpret: bool = True) -> tuple[jax.Array, jax.Array]:
+                     interpret: bool) -> tuple[jax.Array, jax.Array]:
     """Fused single-pass per-(token-block, channel) quantization of (T, D).
 
     One HBM read + int8 write per element (beats the paper's 2-read CUDA
@@ -154,7 +157,7 @@ def quantize_blocked(x: jax.Array, block_size: int = 256, *,
 @functools.partial(jax.jit, static_argnames=("block_d", "out_dtype", "interpret"))
 def dequantize(x_q: jax.Array, scales: jax.Array, *,
                block_d: int | None = None, out_dtype=jnp.float32,
-               interpret: bool = True) -> jax.Array:
+               interpret: bool) -> jax.Array:
     """int8 (T, D) × f32 scales (nb, D) -> (T, D) out_dtype. nb=1 => per-channel."""
     T, D = x_q.shape
     if scales.ndim == 1:

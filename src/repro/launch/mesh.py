@@ -8,6 +8,15 @@ from __future__ import annotations
 import jax
 
 
+def make_mesh(shape, axes):
+    """`jax.make_mesh` with every axis typed Auto. The model code annotates
+    activations with sharding constraints and lets the partitioner place
+    everything else; JAX's default axis type (Explicit) would instead
+    demand an out-sharding on every op whose inputs share a mesh axis."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(shape, axes, axis_types=auto)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """v5e-256 pod: (data=16, model=16); two pods: (pod=2, data=16, model=16).
 
@@ -16,12 +25,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Single-device mesh for smoke tests/examples on CPU."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 # TPU v5e hardware constants (roofline terms; EXPERIMENTS.md §Roofline)

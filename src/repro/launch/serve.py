@@ -3,6 +3,9 @@ cache (continuous batching, per-request sampling, streaming outputs).
 
     PYTHONPATH=src python -m repro.launch.serve --arch internlm2_1_8b \
         --smoke --requests 8 --max-new 16 --temperature 0.8 --top-p 0.9
+
+`parse_args` + `build_engine` are the one way an engine is built from
+these flags; `chip_smoke.py` builds its engine through them too.
 """
 from __future__ import annotations
 
@@ -11,7 +14,9 @@ import sys
 import time
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse the serve flags and resolve the implied ones (--prefix-cache,
+    --prefill-chunk, --watermark and non-int8 dtypes imply --paged)."""
     # the source of truth for valid dtypes — a typo must die in argparse
     # with the real names, not as a KeyError deep in pool init
     from repro.core.quantization import KV_DTYPES
@@ -130,40 +135,58 @@ def main(argv=None):
         ap.error("--kv-cache-plan and --kv-cache-dtype are mutually "
                  "exclusive: a plan assigns every layer's dtype itself "
                  "(DESIGN.md §10)")
-    kv_spec = (args.kv_cache_plan if args.kv_cache_plan is not None
-               else args.kv_cache_dtype or "int8")
+    args.kv_spec = (args.kv_cache_plan if args.kv_cache_plan is not None
+                    else args.kv_cache_dtype or "int8")
     if args.host_pages is not None:
         args.prefix_cache = True     # the host tier keys on chain digests
     if (args.prefix_cache or args.prefill_chunk
-            or args.watermark is not None or kv_spec != "int8"):
+            or args.watermark is not None or args.kv_spec != "int8"):
         args.paged = True
+    return args
 
+
+def build_engine(args: argparse.Namespace):
+    """Model config, random weights from seed 0, and the `LLMEngine` the
+    flags describe. Returns (cfg, engine)."""
     import dataclasses
 
     import jax
-    import numpy as np
 
     from repro.configs import get_config
     from repro.models import transformer
-    from repro.serving import (EngineConfig, LLMEngine, SamplingParams,
-                               kv_cache_memory_report)
+    from repro.serving import EngineConfig, LLMEngine
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.layers is not None:
         cfg = dataclasses.replace(cfg, n_layers=args.layers)
-    rep = kv_cache_memory_report(get_config(args.arch), 128, 32_768)
-    print(f"[serve] {args.arch}: full-size cache at decode_32k "
-          f"fp32={rep['fp32_bytes']/2**30:.0f}GiB "
-          f"int8={rep['int8_bytes']/2**30:.0f}GiB (4x reduction)")
-
     params = transformer.init_params(cfg, jax.random.PRNGKey(0))
     eng = LLMEngine(params, cfg, EngineConfig(
         batch=args.batch, max_len=args.max_len, paged=args.paged,
         n_pages=args.pages, chunk=args.chunk,
         prefix_cache=args.prefix_cache, prefill_chunk=args.prefill_chunk,
         watermark=args.watermark, aging_ticks=args.aging_ticks,
-        kv_cache_dtype=kv_spec, host_pages=args.host_pages,
+        kv_cache_dtype=args.kv_spec, host_pages=args.host_pages,
         evictor=args.evictor, host_tier_dtype=args.host_tier_dtype))
+    return cfg, eng
+
+
+def main(argv=None):
+    args = parse_args(argv)
+
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config
+    from repro.runtime.device import setup_compile_cache
+    from repro.serving import SamplingParams, kv_cache_memory_report
+
+    setup_compile_cache()
+    rep = kv_cache_memory_report(get_config(args.arch), 128, 32_768)
+    print(f"[serve] {args.arch}: full-size cache at decode_32k "
+          f"fp32={rep['fp32_bytes']/2**30:.0f}GiB "
+          f"int8={rep['int8_bytes']/2**30:.0f}GiB (4x reduction)")
+    cfg, eng = build_engine(args)
+    dev = jax.devices()[0]
     rng = np.random.RandomState(0)
     shared_n = min(args.shared_prefix, args.prompt_len)
     shared = rng.randint(0, cfg.vocab, (shared_n,)).astype(np.int32)
@@ -184,9 +207,11 @@ def main(argv=None):
     total_toks = sum(len(o.token_ids) for o in outs)
     mode = ("greedy" if args.temperature == 0 else
             f"T={args.temperature} top_k={args.top_k} top_p={args.top_p}")
+    # wall clock of one cold run, compiles included: not a benchmark
     print(f"[serve] completed {len(outs)}/{args.requests} requests "
           f"({mode}), {total_toks} tokens in {dt:.1f}s "
-          f"({total_toks/dt:.1f} tok/s host-CPU, "
+          f"({total_toks/dt:.1f} tok/s on {dev.platform} "
+          f"{dev.device_kind}, compiles included; "
           f"{total_toks/max(eng.ticks,1):.1f} tokens/dispatch "
           f"over {eng.ticks} ticks)")
     rep = eng.pool_report()

@@ -96,7 +96,6 @@ def _gather_seq(x):
             or "model" in rules.get("batch", ())):
         return act_shard(x, "batch", None, None, None)
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.shard import shard_map_compat
     fsdp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     nf = 1
     for a in fsdp:
@@ -104,9 +103,10 @@ def _gather_seq(x):
     batch_ax = fsdp if fsdp and B % nf == 0 else ()
     in_spec = P(batch_ax if batch_ax else None, None, "model", None)
     out_spec = P(batch_ax if batch_ax else None, None, None, None)
-    return shard_map_compat(
+    return jax.shard_map(
         lambda xl: jax.lax.all_gather(xl, "model", axis=2, tiled=True),
-        mesh=mesh, in_specs=(in_spec,), out_specs=out_spec)(x)
+        mesh=mesh, in_specs=(in_spec,), out_specs=out_spec,
+        check_vma=False)(x)
 
 
 def _sdpa(q, k, v, cfg: ModelConfig, *, causal: bool, window: int | None,

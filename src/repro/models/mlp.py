@@ -63,7 +63,6 @@ def _sp_plan(mesh, x_shape, w_shape):
 
 def _apply_shard_map(p, x, mesh, plan):
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.shard import shard_map_compat
     fsdp, batch_ax = plan
 
     def local_fn(wg, wu, wd, xl):
@@ -82,9 +81,10 @@ def _apply_shard_map(p, x, mesh, plan):
     w_col = P(fsdp if fsdp else None, "model")
     w_row = P("model", fsdp if fsdp else None)
     x_spec = P(batch_ax if batch_ax else None, "model", None)
-    return shard_map_compat(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=(w_col, w_col, w_row, x_spec),
-        out_specs=x_spec)(p["w_gate"], p["w_up"], p["w_down"], x)
+        out_specs=x_spec, check_vma=False)(p["w_gate"], p["w_up"],
+                                           p["w_down"], x)
 
 
 def _ag(w, axes, axis):

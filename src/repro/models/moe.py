@@ -132,7 +132,6 @@ def _apply_local(p, x, cfg: ModelConfig):
 
 def _apply_shard_map(p, x, cfg: ModelConfig, mesh):
     from jax.sharding import PartitionSpec as P
-    from repro.parallel.shard import shard_map_compat
 
     fsdp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
     tp = "model" if "model" in mesh.axis_names else None
@@ -172,10 +171,10 @@ def _apply_shard_map(p, x, cfg: ModelConfig, mesh):
             aux = jax.lax.pmean(aux, batch_ax)   # replicate the scalar
         return out, aux
 
-    out, aux = shard_map_compat(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(w_spec, w_spec, wd_spec, P(None, None), x_spec),
-        out_specs=(x_spec, P()),
+        out_specs=(x_spec, P()), check_vma=False,
     )(p["w_gate"], p["w_up"], p["w_down"], p["router"], x)
     return out, aux
 

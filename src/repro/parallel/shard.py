@@ -124,21 +124,6 @@ def named_sharding(mesh: Mesh, *parts) -> NamedSharding:
     return NamedSharding(mesh, P(*parts))
 
 
-def shard_map_compat(fn, *, mesh, in_specs, out_specs):
-    """`shard_map` across jax versions: import moved (experimental -> top
-    level at 0.7) and the replication-check kwarg was renamed
-    (check_rep -> check_vma); we always disable it."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:                                # pragma: no cover
-        from jax.experimental.shard_map import shard_map as _sm
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _sm(fn, check_vma=False, **kw)
-    except TypeError:
-        return _sm(fn, check_rep=False, **kw)
-
-
 # ---------------------------------------------------------------------------
 # Paged KV cache sharding (core/paging.py)
 #
@@ -151,10 +136,10 @@ def shard_map_compat(fn, *, mesh, in_specs, out_specs):
 # ---------------------------------------------------------------------------
 
 PAGE_POOL_LOGICAL: dict[str, tuple[str | None, ...]] = {
-    "k_q": ("pages", None, "kv_heads", None),   # (n_pages, ps, H_kv, D)
-    "v_q": ("pages", None, "kv_heads", None),
-    "k_s": ("pages", "kv_heads", None),         # (n_pages, H_kv, D)
-    "v_s": ("pages", "kv_heads", None),
+    "k_q": ("pages", "kv_heads", None, None),   # (n_pages, H_kv, ps, D)
+    "v_q": ("pages", "kv_heads", None, None),
+    "k_s": ("pages", "kv_heads", None, None),   # (n_pages, H_kv, 1, D)
+    "v_s": ("pages", "kv_heads", None, None),
     "free_stack": (None,),
     "n_free": (),
 }

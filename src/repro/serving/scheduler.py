@@ -324,6 +324,12 @@ class ContinuousBatcher:
             pc = prefill_chunk or 4 * self.page_size
             self.prefill_chunk_tokens = -(-pc // self.page_size) * \
                 self.page_size
+            # a chunk the fused prefill kernel cannot hold in VMEM fails
+            # here, at construction, not at its first dispatch on the chip
+            from repro.kernels.quant_prefill import check_prefill_fits
+            check_prefill_fits(cfg.n_heads // cfg.n_kv_heads,
+                               self.prefill_chunk_tokens, self.page_size,
+                               cfg.head_dim)
             # one jitted chunk fn per (static history bound, fused-toggle,
             # kv dtype spec); the bound set is pow2, the toggle and dtype
             # read live from self.config per dispatch (DESIGN.md §9; a
@@ -1396,8 +1402,8 @@ class ContinuousBatcher:
     def _demote_to_host(self, page: int, digest: bytes) -> bool:
         """Demote one indexed device page to the host tier (DESIGN.md §11):
         copy its quantized values + scale rows out of every cache leaf
-        (page axis -4, scale axis -3 — stacked uniform state carries
-        leading layer-group dims) and store them under the chain digest,
+        (page axis -4 — stacked uniform state carries leading layer-group
+        dims) and store them under the chain digest,
         recompressing to `host_tier_dtype` when set. Installed as the
         allocator's ``demote_hook`` (reclaim-time demotion) and called
         eagerly by the preempt-by-swap arm. Skips when the digest is
@@ -1415,10 +1421,10 @@ class ContinuousBatcher:
             dt = leaf.pool.kv_dtype
             host_dt = tier.dtype or dt
             kq, ks = TIER.repack_page(leaf.pool.k_q[..., page, :, :, :],
-                                      leaf.pool.k_s[..., page, :, :],
+                                      leaf.pool.k_s[..., page, :, :, :],
                                       dt, host_dt)
             vq, vs = TIER.repack_page(leaf.pool.v_q[..., page, :, :, :],
-                                      leaf.pool.v_s[..., page, :, :],
+                                      leaf.pool.v_s[..., page, :, :, :],
                                       dt, host_dt)
             payloads.append((kq, ks, vq, vs))
             dtypes.append(host_dt)
@@ -1460,16 +1466,13 @@ class ContinuousBatcher:
                     kq, ks = TIER.repack_page(kq, ks, src, dt)
                     vq, vs = TIER.repack_page(vq, vs, src, dt)
                 quads.append((kq, ks, vq, vs))
-            kq = np.stack([q[0] for q in quads], axis=-4)
-            ks = np.stack([q[1] for q in quads], axis=-3)
-            vq = np.stack([q[2] for q in quads], axis=-4)
-            vs = np.stack([q[3] for q in quads], axis=-3)
+            kq, ks, vq, vs = (np.stack(a, axis=-4) for a in zip(*quads))
             pool = dataclasses.replace(
                 x.pool,
                 k_q=x.pool.k_q.at[..., ids, :, :, :].set(jnp.asarray(kq)),
-                k_s=x.pool.k_s.at[..., ids, :, :].set(jnp.asarray(ks)),
+                k_s=x.pool.k_s.at[..., ids, :, :, :].set(jnp.asarray(ks)),
                 v_q=x.pool.v_q.at[..., ids, :, :, :].set(jnp.asarray(vq)),
-                v_s=x.pool.v_s.at[..., ids, :, :].set(jnp.asarray(vs)))
+                v_s=x.pool.v_s.at[..., ids, :, :, :].set(jnp.asarray(vs)))
             return dataclasses.replace(x, pool=pool)
 
         def rec(x):

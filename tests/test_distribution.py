@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.parallel.shard import logical_spec, mesh_context, act_shard
 from repro.launch.specs import (batch_shardings, cache_shardings,
                                 param_shardings)
@@ -24,7 +25,7 @@ needs_devices = pytest.mark.skipif(
 
 
 def _mesh():
-    return jax.make_mesh((2, 4), ("data", "model"))
+    return make_mesh((2, 4), ("data", "model"))
 
 
 @needs_devices
@@ -47,7 +48,7 @@ class TestLogicalSpec:
         assert spec == P("model", None)
 
     def test_pod_axis_composes(self):
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         spec = logical_spec(("batch", None), (16, 8), mesh)
         assert spec == P(("pod", "data"), None)
 
@@ -94,8 +95,8 @@ class TestParamShardings:
         cfgq = QuantConfig(granularity="per_block", block_size=8)
         cache = PagedQuantizedKVCache.init(8, 4, 64, 16, cfgq, n_pages=32)
         specs = paged_cache_specs(cache, mesh)
-        assert specs.pool.k_q == P(None, None, "model", None)
-        assert specs.pool.k_s == P(None, "model", None)
+        assert specs.pool.k_q == P(None, "model", None, None)
+        assert specs.pool.k_s == P(None, "model", None, None)
         assert specs.pool.free_stack == P(None)
         assert specs.page_table == P("data", None)
         assert specs.length == P("data")
@@ -107,7 +108,7 @@ class TestParamShardings:
         cfgq = QuantConfig(granularity="per_block", block_size=8)
         cache = PagedQuantizedKVCache.init(8, 4, 64, 16, cfgq, n_pages=32)
         sharded = jax.device_put(cache, paged_cache_shardings(cache, mesh))
-        assert sharded.pool.k_q.sharding.spec == P(None, None, "model", None)
+        assert sharded.pool.k_q.sharding.spec == P(None, "model", None, None)
         assert sharded.page_table.sharding.spec == P("data", None)
 
 

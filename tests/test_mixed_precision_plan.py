@@ -196,7 +196,8 @@ def test_alternating_plan_per_layer_pool_geometry(serving_model):
         assert c.pool.k_q.dtype == uc.pool.k_q.dtype
         assert c.pool.k_q.shape == uc.pool.k_q.shape
         ps = c.pool.page_size
-        assert c.pool.k_q.shape[1] == Q.packed_tokens(ps, dt)
+        # (n_pages, H_kv, tokens_packed, D)
+        assert c.pool.k_q.shape[2] == Q.packed_tokens(ps, dt)
         assert PG.page_bytes_for(ps, cfg.n_kv_heads, cfg.head_dim, dt) \
             == PG.page_bytes_for(ps, cfg.n_kv_heads, cfg.head_dim,
                                  uc.pool.kv_dtype)

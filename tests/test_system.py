@@ -5,6 +5,8 @@ the serving path generates coherently with the INT8 cache, quantized-cache
 serving matches unquantized within the paper's error model, and the
 launchers run.
 """
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -18,6 +20,8 @@ from repro.data import DataConfig, SyntheticLM
 from repro.models import transformer as T
 from repro.optim import AdamWConfig
 from repro.training.step import init_opt_state, make_train_step
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -130,10 +134,10 @@ def test_train_launcher_cli(tmp_path):
          "--seq", "32", "--ckpt-dir", str(tmp_path)],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root",
+             "HOME": os.environ.get("HOME", str(REPO)),
              # without this the stripped env lets jax probe for a TPU
              # runtime and the subprocess stalls for minutes
-             "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+             "JAX_PLATFORMS": "cpu"}, cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "step" in r.stdout
     from repro.checkpoint import latest_step
@@ -151,7 +155,8 @@ def test_serve_launcher_cli():
          "--seed", "0", "--stop", "<511>"],
         capture_output=True, text=True, timeout=600,
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-             "HOME": "/root", "JAX_PLATFORMS": "cpu"}, cwd="/root/repo")
+             "HOME": os.environ.get("HOME", str(REPO)),
+             "JAX_PLATFORMS": "cpu"}, cwd=REPO)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "completed 4/4" in r.stdout
     assert "lifecycle" in r.stdout

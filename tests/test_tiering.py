@@ -120,9 +120,9 @@ def test_repack_same_dtype_is_bitwise_and_cross_dtype_bounded():
     rng = np.random.RandomState(7)
     x = rng.randn(2, PAGE, 4).astype(np.float32)      # (H, ps, D)
     q8, s8 = Q.quantize_page_matrix(x, "int8")        # (H, tp, D), (H, D)
-    # pool layout: tokens on axis -3, heads -2
-    qp = np.asarray(np.moveaxis(np.asarray(q8), -2, -3))
-    sp = np.asarray(s8)
+    # pool layout: heads-major values, one (1, D) scale row per head
+    qp = np.asarray(q8)
+    sp = np.asarray(s8)[..., None, :]
     q_same, s_same = TIER.repack_page(qp, sp, "int8", "int8")
     assert np.array_equal(q_same, qp) and np.array_equal(s_same, sp)
     # int8 -> int4 -> int8 round trip: error bounded by the sum of both
@@ -130,7 +130,7 @@ def test_repack_same_dtype_is_bitwise_and_cross_dtype_bounded():
     q4, s4 = TIER.repack_page(qp, sp, "int8", "int4")
     qb, sb = TIER.repack_page(q4, s4, "int4", "int8")
     dq = lambda q, s, dt: np.asarray(Q.dequantize_pages(
-        np.moveaxis(np.asarray(q), -3, -2), np.asarray(s)[..., None, :], dt))
+        np.asarray(q), np.asarray(s), dt))
     x8, x48 = dq(qp, sp, "int8"), dq(qb, sb, "int8")
     amax = np.abs(x).max(axis=-2, keepdims=True)
     bound = amax / 127.0 + amax / 7.0 + amax / 127.0   # int8 + int4 + int8
