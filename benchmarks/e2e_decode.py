@@ -13,9 +13,8 @@ full context vs all rows at 25% context):
     dead-block DMA skipping) vs the seed per-(row, head) vmap fan-out, and
     the paged kernel with its bounded page walk. Interpret-mode wall times
     are CPU-interpreter-bound and reported as such; the hardware-level
-    result is the DMA-skip ratio and the HBM-roofline projection over the
-    bytes each variant actually streams (the repo's standard projection,
-    benchmarks/common.py).
+    result is the HBM-roofline projection over the bytes each variant
+    actually streams (the repo's standard projection, benchmarks/common.py).
   * capacity projection for the full-size archs on the TPU target (where
     cache reads dominate decode at long context).
 
@@ -56,9 +55,9 @@ def _streamed_bytes(lengths, *, skip: bool) -> int:
     """HBM bytes one decode launch streams for K/V tiles + scale rows.
 
     With dead-block skipping only live blocks are DMA'd (clamped steps reuse
-    the resident tile — same live-block count dma_skip_ratio uses); without
-    it every grid step streams its block. The resident q block and the tiny
-    partials are counted once per (row, head).
+    the resident tile); without it every grid step streams its block. The
+    resident q block and the tiny partials are counted once per (row,
+    head).
     """
     from repro.kernels.quant_attention import live_blocks
     nt = KT // KBT
@@ -87,11 +86,9 @@ def _kernel_mix(lengths) -> dict:
         q, kq, kss, vq, vs, L, interpret=True), iters=3)
     t_paged = time_fn(lambda: QA.paged_attention_decode_partials(
         q, pk, pks, pv, pvs, table, flushed, interpret=True), iters=3)
-    skip = QA.dma_skip_ratio(np.asarray(lengths), KBT, KT)
     proj = _streamed_bytes(lengths, skip=True) / HBM_BW
     proj_noskip = _streamed_bytes(lengths, skip=False) / HBM_BW
     return {
-        "dma_skip_ratio": skip,
         "contiguous": {
             "interp_us": t_flat * 1e6,
             "seed_vmap_interp_us": t_seed * 1e6,
@@ -221,8 +218,7 @@ def run():
         rows.append({
             "bench": "e2e_decode", "config": f"kernel_{name}",
             "us": kern["contiguous"]["tpu_proj_us"],
-            "detail": (f"dma_skip={kern['dma_skip_ratio']:.2f} "
-                       f"proj_speedup={kern['contiguous']['proj_speedup_vs_no_skip']:.2f} "
+            "detail": (f"proj_speedup={kern['contiguous']['proj_speedup_vs_no_skip']:.2f} "
                        f"interp_us={kern['contiguous']['interp_us']:.0f} "
                        f"paged_interp_us={kern['paged']['interp_us']:.0f}"),
         })

@@ -14,10 +14,10 @@ caching itself:
 
   * TTFT (time to first token, mean over requests; each request's clock
     runs from its submit to the scheduler's first-token stamp
-    `Request.first_token_time`, i.e. the prefill boundary — NOT to the
-    first observed decode output, which would fold a whole decode-scan
-    dispatch into every TTFT) — the metric prefix caching targets: hit
-    chunks skip compute entirely
+    `Request.first_token_time`, the end of the tick that delivers the
+    token, so it holds that tick's decode scan as well as the prefill)
+    — the metric prefix caching targets: hit chunks skip compute
+    entirely
   * tokens/s over the whole queue (host wall-clock)
   * page hit rate, reclaim and CoW counters from the host allocator
 
@@ -85,9 +85,7 @@ def _prompts(rng, frac, n=N_REQUESTS):
 def _drive(batcher, prompts):
     """Submit everything at t0; TTFT per request is the scheduler's own
     first-token stamp minus the submit stamp (`Request.first_token_time`,
-    recorded at the prefill boundary) — so TTFT measures prefill. The
-    earlier generated-poll measurement charged every request a full
-    decode-scan dispatch on top, a constant that diluted every ratio."""
+    stamped when the tick that delivers the first token ends)."""
     reqs = [Request(uid=i, prompt=p, max_new_tokens=MAX_NEW)
             for i, p in enumerate(prompts)]
     for r in reqs:

@@ -1,6 +1,6 @@
 # One function per paper table. Print ``name,us_per_call,derived`` CSV.
 # ``--json`` additionally writes BENCH_decode.json (machine-readable decode
-# perf: tokens/s, us/step, DMA-skip ratio for contiguous vs paged at two
+# perf: tokens/s, us/step, projected kernel bytes for contiguous vs paged at two
 # length mixes) so the perf trajectory is tracked across PRs.
 import argparse
 import json

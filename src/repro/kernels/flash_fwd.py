@@ -19,8 +19,7 @@ over: pl.when drops the compute, and the k/v index maps clamp dead block
 indices to the q block's causal frontier (`min(j, last_live_block)`, the
 same clamp-to-last-live trick as quant_attention.py's page walk), so the
 pipeline re-reads the resident block instead of streaming HBM for kv
-blocks entirely in the causal future. `dma_skip_ratio` reports the
-fraction of grid steps whose kv stream is elided.
+blocks entirely in the causal future.
 """
 from __future__ import annotations
 
@@ -100,8 +99,7 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
     block's causal frontier, so kv blocks wholly in the future — whose
     compute pl.when already drops — stream no DMA either: the pipeline
     sees a repeated block index and re-uses the resident tile. Invisible
-    to results (those blocks were fully masked); `dma_skip_ratio` gives
-    the fraction of grid steps elided."""
+    to results (those blocks were fully masked)."""
     B, H, S, D = q.shape
     Hkv, T = k.shape[1], k.shape[2]
     G = H // Hkv
@@ -145,22 +143,3 @@ def flash_prefill(q, k, v, *, causal: bool = True, window: int | None = None,
 
     out = jax.vmap(jax.vmap(one))(qg, k, v)           # (B, Hkv, G*S, D)
     return out.reshape(B, H, S, D)
-
-
-def dma_skip_ratio(S: int, T: int, G: int = 1, *, causal: bool = True,
-                   kv_offset: int = 0, block_q: int = 256,
-                   block_k: int = 256) -> float:
-    """Fraction of (q block, kv block) grid steps whose kv HBM stream the
-    index-map clamp elides for these shapes (structural metric, mirroring
-    quant_attention.dma_skip_ratio). 0 for non-causal attention — every
-    kv block is live for every q block."""
-    if not causal:
-        return 0.0
-    block_q = min(block_q, S)
-    block_k = min(block_k, T)
-    nq, nk = (G * S) // block_q, T // block_k
-    skipped = 0
-    for i in range(nq):
-        last_live = (kv_offset + (i * block_q) % S + block_q - 1) // block_k
-        skipped += max(nk - 1 - min(last_live, nk - 1), 0)
-    return skipped / (nq * nk)

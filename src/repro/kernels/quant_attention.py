@@ -68,16 +68,6 @@ def live_blocks(lengths, block: int, max_len: int):
     return np.maximum(-(-lens // block), 1)
 
 
-def dma_skip_ratio(lengths, block: int, max_len: int) -> float:
-    """Fraction of token-block grid steps whose HBM stream is skipped by the
-    index_map clamp: 1 - sum_b(live_blocks_b) / (B * NT). Structural metric
-    (hardware independent) reported by benchmarks/e2e_decode.py."""
-    import numpy as np
-    live = live_blocks(lengths, block, max_len)
-    nt = max_len // block
-    return float(1.0 - live.sum() / (live.size * nt))
-
-
 # ---------------------------------------------------------------------------
 # Shared online-softmax tile update
 # ---------------------------------------------------------------------------
@@ -481,6 +471,7 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
                    jax.ShapeDtypeStruct((B, Hkv, Gp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hkv, Gp, 1), jnp.float32)],
         interpret=interpret,
+        name="_paged_decode",        # the kernel's name in a device trace
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       qg, pool_kq, pool_ks, pool_vq, pool_vs)
 

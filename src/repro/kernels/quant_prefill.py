@@ -216,6 +216,7 @@ def _paged_prefill(qg, kc, vc, pool_kq, pool_ks, pool_vq, pool_vs,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=PREFILL_VMEM_LIMIT),
         interpret=interpret,
+        name="_paged_prefill",       # the kernel's name in a device trace
     )(pt.astype(jnp.int32), hist_len.astype(jnp.int32),
       valid.astype(jnp.int32), qg, kc, vc,
       pool_kq, pool_ks, pool_vq, pool_vs)
@@ -251,18 +252,3 @@ def paged_attention_prefill(q, k, v, pool_kq, pool_ks, pool_vq, pool_vs,
                          skip_dead=skip_dead, interpret=interpret,
                          kv_dtype=kv_dtype)
     return out.reshape(B, H, C, D)
-
-
-def prefill_dma_skip_ratio(hist_lens, page_size: int,
-                           hist_blocks: int) -> float:
-    """Fraction of history grid steps whose HBM page stream is skipped by
-    the index_map clamp across a dispatch (structural metric, mirroring
-    quant_attention.dma_skip_ratio). 0 when the dispatch has no history
-    axis (hist_blocks == 0)."""
-    import numpy as np
-    if hist_blocks == 0:
-        return 0.0
-    lens = np.minimum(np.asarray(hist_lens, np.int64),
-                      hist_blocks * page_size)
-    live = np.maximum(-(-lens // page_size), 1)
-    return float(1.0 - live.sum() / (live.size * hist_blocks))

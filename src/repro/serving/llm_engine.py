@@ -38,7 +38,8 @@ class RequestOutput:
     `serving.params.FINISH_REASONS` ("stop_token" | "stop_string" |
     "length" | "aborted"). `metrics` carries the host-clock lifecycle
     timestamps plus derived latencies: ttft_s (first token - submit) and
-    decode_s (finish - first token, None until finished)."""
+    decode_s (finish - first token, None until finished). Both stamps
+    are taken when the `step()` that delivers the token ends."""
     uid: int
     new_token_ids: list[int]
     token_ids: list[int]
@@ -60,6 +61,9 @@ class LLMEngine:
         # own — delivered by the next step() call instead of being dropped
         self._undelivered: list[RequestOutput] = []
         self._next_uid = 0
+        # host seconds of every step() less its program calls and its
+        # waits on the device (runtime/trace.py)
+        self.tick_host_s = 0.0
 
     def add_request(self, prompt, sampling_params: SamplingParams | None
                     = None, *, uid: int | None = None,
@@ -111,11 +115,16 @@ class LLMEngine:
         made progress (new tokens) or finished this tick — plus any
         snapshots a concurrent `generate()` drain produced for online
         requests it didn't own."""
-        outs, self._undelivered = self._undelivered, []
-        self.batcher.step()
-        for uid, req in list(self._live.items()):
-            if req.done or len(req.generated) > self._emitted.get(uid, 0):
-                outs.append(self._snapshot(req))
+        spans = self.batcher.spans
+        with spans.span("engine.step", tick=self.batcher.ticks + 1) as tick:
+            outs, self._undelivered = self._undelivered, []
+            self.batcher.step()
+            with spans.span("engine.outputs"):
+                for uid, req in list(self._live.items()):
+                    if req.done or len(req.generated) > \
+                            self._emitted.get(uid, 0):
+                        outs.append(self._snapshot(req))
+        self.tick_host_s += tick.host_seconds
         return outs
 
     def abort(self, uid: int) -> RequestOutput | None:
@@ -174,7 +183,10 @@ class LLMEngine:
 
     # -- introspection passthrough -----------------------------------------
     def pool_report(self) -> dict:
-        return self.batcher.pool_report()
+        """The scheduler's `pool_report` and ``tick_host_s``: the seconds
+        of every `step()` less its program calls (``dispatch.*`` spans) and its
+        waits on the device (``wait.*``)."""
+        return {**self.batcher.pool_report(), "tick_host_s": self.tick_host_s}
 
     @property
     def ticks(self) -> int:

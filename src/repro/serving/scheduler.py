@@ -94,6 +94,7 @@ failure modes diagnostic rather than livelocks.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 import warnings
 from collections import deque
@@ -106,6 +107,7 @@ import numpy as np
 from repro.core import paging as PG
 from repro.core import quantization as Q
 from repro.core.paging import PagedQuantizedKVCache
+from repro.runtime import trace as TR
 from repro.runtime.fault import StallWatchdog
 from repro.serving.params import (EngineConfig, SamplingParams,
                                   default_detokenize, request_key,
@@ -154,7 +156,11 @@ class Request:
     completion `finish_reason` is one of `serving.params.FINISH_REASONS`
     ("stop_token" | "stop_string" | "length" | "aborted") and the
     timestamps record submit / first-token (TTFT) / finish times
-    (`time.perf_counter` seconds, host clock).
+    (`time.perf_counter` seconds, host clock). A token is delivered when
+    the `ContinuousBatcher.step` that appended it to `generated` returns,
+    so the first-token and finish times are stamped at the end of that
+    tick, after its decode scan, not at the prefill boundary where the
+    first token is sampled.
 
     `max_new_tokens=None` takes the budget from
     `sampling.max_new_tokens` (resolved at submit) — there is ONE
@@ -229,10 +235,18 @@ class ContinuousBatcher:
         self.detokenize = config.detokenize or default_detokenize
         # request-lifecycle bookkeeping (DESIGN.md §6): uids queued or on a
         # row (duplicates rejected at submit), abort counter, and recorded
-        # per-request TTFTs for the pool_report percentiles
+        # per-request TTFTs for the pool_report percentiles and sum
         self._inflight_uids: set[int] = set()
         self.aborted_requests = 0
         self._ttfts: list[float] = []
+        # host spans of the tick and the cumulative counters made from
+        # them (runtime/trace.py; exported by pool_report): admissions and
+        # their queue wait, and the part of the first tokens' time from
+        # submission that the ticks' decode scans took
+        self.spans = TR.Spans()
+        self.admissions = 0
+        self.queue_wait_s = 0.0
+        self.first_token_scan_s = 0.0
         # decode tokens per device dispatch: None = scan to the next
         # completion boundary; 1 = per-token ticks (also forced for encdec,
         # which has no transformer decode_scan path)
@@ -276,6 +290,7 @@ class ContinuousBatcher:
         self.preempt_recompute_resumes = 0
         self.decode_stall_ticks = 0
         self.prefill_tokens_computed = 0
+        self.prefill_tokens_dispatched = 0      # batch x width per chunk
         self.decode_tokens_computed = 0
         # tiered KV cache (DESIGN.md §11): host swap tier + cost model +
         # per-uid swap-wait state; inert (None/empty) without host_pages
@@ -359,7 +374,7 @@ class ContinuousBatcher:
             cfg, max_len=max_len, paged=paged, n_pages=n_pages,
             kv_cache_dtype=self.kv_cache_dtype)
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode)
+        self._decode = TR.program(decode, "decode_fn")
         self._init_state = init_state
         self.queue: deque[Request] = deque()
         self.rows: list[Request | None] = [None] * batch
@@ -442,6 +457,8 @@ class ContinuousBatcher:
         if req.priority is None:
             req.priority = req.sampling.priority
         req.submit_time = time.perf_counter()
+        req._queued_at = req.submit_time    # queue wait, until admission
+        req._scan_mark = self._scan_s()     # decode-scan seconds so far
         req._submit_tick = self.ticks       # aging clock (DESIGN.md §8)
         req._arrival = self._seq            # priority tie-break: FCFS
         self._seq += 1
@@ -526,11 +543,33 @@ class ContinuousBatcher:
                                           s["step"]))
 
     # -- lifecycle helpers (DESIGN.md §6) ----------------------------------
-    def _record_first_token(self, r: Request):
-        if r.first_token_time is None:
-            r.first_token_time = time.perf_counter()
-            if r.submit_time is not None:
-                self._ttfts.append(r.first_token_time - r.submit_time)
+    def _scan_s(self) -> float:
+        """Seconds the ticks' decode scans have taken so far, from the
+        scan's launch to the end of its tokens' bookkeeping: the host's
+        part of `sched.decode`, the wait for the tokens and their
+        bookkeeping. The program call (`dispatch.decode`) is left out: the
+        runtime can hold it until the tick's chunk prefill, enqueued
+        before it, has finished (DESIGN.md §6)."""
+        sp = self.spans
+        return sp.total("sched.decode", "wait.decode",
+                        "sched.bookkeeping") - sp.total("dispatch.decode")
+
+    def _deliver(self, done: list[Request]):
+        """End of a tick: what it appended to `generated` is delivered
+        now, when `step` returns it (DESIGN.md §6). Stamps the finish time
+        of the requests it completed and the first-token time of those
+        whose first token it appended, and adds each first token to the
+        counters: its time from submission, and the part of that time the
+        decode scans of the ticks in between took. A resumed request
+        (DESIGN.md §8) keeps the first-token time it already has."""
+        now, scan = time.perf_counter(), self._scan_s()
+        for r in done:
+            r.finish_time = now
+        for r in itertools.chain(done, self.rows):
+            if r is not None and r.first_token_time is None and r.generated:
+                r.first_token_time = now
+                self._ttfts.append(now - r.submit_time)
+                self.first_token_scan_s += scan - r._scan_mark
 
     def _finish(self, r: Request, reason: str):
         r.done = True
@@ -622,6 +661,7 @@ class ContinuousBatcher:
         self._progressed = False
         self._ensure_backend_dtype()
         done = self._step_paged() if self.paged else self._step_contiguous()
+        self._deliver(done)
         if done:
             self._progressed = True
         if self._progressed:
@@ -706,7 +746,7 @@ class ContinuousBatcher:
             self.cfg, max_len=self.max_len, paged=True,
             n_pages=self.n_pages, kv_cache_dtype=want)
         self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode)
+        self._decode = TR.program(decode, "decode_fn")
         self._init_state = init_state
         self.state = None                      # rebuilt lazily next tick
         # indexed/cached pages hold bytes in the OLD format — a fresh
@@ -771,7 +811,7 @@ class ContinuousBatcher:
         return None
 
     def _finish_tick(self, active: list[int], nxt: np.ndarray) -> list[Request]:
-        done = []
+        done, ended = [], []
         for i in active:
             r = self.rows[i]
             r.generated.append(int(self.tok[i, 0]))
@@ -781,7 +821,8 @@ class ContinuousBatcher:
             if reason is not None:
                 self._finish(r, reason)
                 done.append(r)
-                self._release_row(i)
+                ended.append(i)
+        self._release_rows(ended, done)
         return done
 
     # -- chunked scanned decode --------------------------------------------
@@ -827,7 +868,7 @@ class ContinuousBatcher:
             def run(params, tok, state, pos, row_mask, sampling):
                 return T.decode_scan(params, tok, cfg, state, pos, steps=n,
                                      row_mask=row_mask, sampling=sampling)
-            fn = self._chunk_fns[key] = jax.jit(run)
+            fn = self._chunk_fns[key] = TR.program(run, "run")
         return fn
 
     def _finish_chunk(self, active: list[int], toks: np.ndarray,
@@ -839,7 +880,7 @@ class ContinuousBatcher:
         tokens are discarded — decode is causal, so tokens before the stop
         are unaffected by what was appended after (DESIGN.md §6)."""
         n = toks.shape[0]
-        done = []
+        done, ended = [], []
         for i in active:
             r = self.rows[i]
             finished = False
@@ -851,11 +892,12 @@ class ContinuousBatcher:
                     self._finish(r, reason)
                     finished = True
                     done.append(r)
-                    self._release_row(i)
+                    ended.append(i)
                     break
             if not finished:
                 self.tok[i, 0] = pending[i, 0]
                 self.pos[i] += n
+        self._release_rows(ended, done)
         return done
 
     def _decode_tick(self, active: list[int],
@@ -873,23 +915,40 @@ class ContinuousBatcher:
         self.decode_tokens_computed += n * len(active)
         if self.paged and self.cow_armed and self._cow_retarget(active, n):
             self._sync_device()          # retargeted tables before the scan
-        args = (self.params, jnp.asarray(self.tok), self.state,
-                jnp.asarray(self.pos))
-        if row_mask is not None:
-            args += (jnp.asarray(row_mask),)
+        sp = self.spans
         if n == 1:          # per-token path (chunk=1 / encdec)
-            logits, self.state = self._decode(*args)
-            return self._finish_tick(
-                active, self._sample_rows(logits, active, offset=1))
-        sampling = (self._sampling_arrays(1)
-                    if self._needs_sampling(active) else None)
-        pending, self.state, toks = self._chunk_fn(n)(
-            self.params, jnp.asarray(self.tok), self.state,
-            jnp.asarray(self.pos),
-            jnp.asarray(row_mask) if row_mask is not None else None,
-            sampling)
-        return self._finish_chunk(active, np.asarray(toks),
-                                  np.asarray(pending))
+            with sp.span("sched.decode", n=1, rows=len(active)):
+                args = (self.params, jnp.asarray(self.tok), self.state,
+                        jnp.asarray(self.pos))
+                if row_mask is not None:
+                    args += (jnp.asarray(row_mask),)
+                with sp.span("dispatch.decode"):
+                    logits, self.state = self._decode(*args)
+            with sp.span("wait.decode"):
+                nxt = self._sample_rows(logits, active, offset=1)
+            with sp.span("sched.bookkeeping"):
+                return self._finish_tick(active, nxt)
+        with sp.span("sched.decode", n=n, rows=len(active)):
+            sampling = (self._sampling_arrays(1)
+                        if self._needs_sampling(active) else None)
+            args = (self.params, jnp.asarray(self.tok), self.state,
+                    jnp.asarray(self.pos),
+                    jnp.asarray(row_mask) if row_mask is not None else None,
+                    sampling)
+            with sp.span("dispatch.decode"):
+                pending, self.state, toks = self._chunk_fn(n)(*args)
+        with sp.span("wait.decode"):
+            toks, pending = np.asarray(toks), np.asarray(pending)
+        with sp.span("sched.bookkeeping"):
+            return self._finish_chunk(active, toks, pending)
+
+    def _release_rows(self, rows: list[int], reqs: list[Request]):
+        """`_release_row` for the rows a tick's bookkeeping finished
+        (their requests `reqs`), under one span (DESIGN.md §6)."""
+        if rows:
+            with self.spans.span("sched.release", uids=TR.uids(reqs)):
+                for i in rows:
+                    self._release_row(i)
 
     def _release_row(self, i: int):
         """Return row ``i`` to the pool. Paged: decref-with-reclaim — in
@@ -942,7 +1001,9 @@ class ContinuousBatcher:
                               np.asarray(r.generated[gb:], np.int32)])
         ext = ext[:(kept // ps) * ps - nb * ps]
         parent = chain[-1] if chain else None        # S < ps: seed the chain
-        for j, h in enumerate(PG.chain_hashes(ext, ps, parent=parent)):
+        with self.spans.span("cache.hash", pages=len(ext) // ps):
+            hashes = PG.chain_hashes(ext, ps, parent=parent)
+        for j, h in enumerate(hashes):
             self.allocator.register(int(self.tables[i, nb + j]), h)
 
     # -- contiguous backend ------------------------------------------------
@@ -1005,8 +1066,6 @@ class ContinuousBatcher:
                     continue
                 self.tok[i, 0] = nxt[i]
                 self.pos[i] = S
-                if not r.generated:              # first token just drawn
-                    self._record_first_token(r)
             active = [i for i in active if self.rows[i] is not None]
         if not active:
             return done0
@@ -1022,36 +1081,38 @@ class ContinuousBatcher:
         """Push host allocator state (page tables, per-row lengths, free
         list) into every layer's cache leaf. Lengths: active rows mirror
         self.pos; freed rows reset to 0."""
-        lengths = np.where(np.asarray([r is not None for r in self.rows]),
-                           self.pos, 0).astype(np.int32)
-        stack = np.zeros((self.n_pages,), np.int32)
-        stack[:len(self.free_pages)] = self.free_pages
-        n_free = np.int32(len(self.free_pages))
-        tables = self.tables
+        with self.spans.span("sched.sync"):
+            lengths = np.where(np.asarray([r is not None for r in self.rows]),
+                               self.pos, 0).astype(np.int32)
+            stack = np.zeros((self.n_pages,), np.int32)
+            stack[:len(self.free_pages)] = self.free_pages
+            n_free = np.int32(len(self.free_pages))
+            tables = self.tables
 
-        def upd(c: PagedQuantizedKVCache) -> PagedQuantizedKVCache:
-            pool = dataclasses.replace(
-                c.pool,
-                free_stack=jnp.broadcast_to(jnp.asarray(stack),
-                                            c.pool.free_stack.shape),
-                n_free=jnp.broadcast_to(jnp.asarray(n_free),
-                                        c.pool.n_free.shape))
-            return dataclasses.replace(
-                c, pool=pool,
-                page_table=jnp.broadcast_to(jnp.asarray(tables),
-                                            c.page_table.shape),
-                length=jnp.broadcast_to(jnp.asarray(lengths), c.length.shape))
+            def upd(c: PagedQuantizedKVCache) -> PagedQuantizedKVCache:
+                pool = dataclasses.replace(
+                    c.pool,
+                    free_stack=jnp.broadcast_to(jnp.asarray(stack),
+                                                c.pool.free_stack.shape),
+                    n_free=jnp.broadcast_to(jnp.asarray(n_free),
+                                            c.pool.n_free.shape))
+                return dataclasses.replace(
+                    c, pool=pool,
+                    page_table=jnp.broadcast_to(jnp.asarray(tables),
+                                                c.page_table.shape),
+                    length=jnp.broadcast_to(jnp.asarray(lengths),
+                                            c.length.shape))
 
-        def rec(x):
-            if isinstance(x, PagedQuantizedKVCache):
-                return upd(x)
-            if isinstance(x, dict):
-                return {k: rec(v) for k, v in x.items()}
-            if isinstance(x, (list, tuple)):
-                return type(x)(rec(v) for v in x)
-            return x
+            def rec(x):
+                if isinstance(x, PagedQuantizedKVCache):
+                    return upd(x)
+                if isinstance(x, dict):
+                    return {k: rec(v) for k, v in x.items()}
+                if isinstance(x, (list, tuple)):
+                    return type(x)(rec(v) for v in x)
+                return x
 
-        self.state = rec(self.state)
+            self.state = rec(self.state)
 
     # -- varlen chunked admission + prefix caching (DESIGN.md §7) ----------
     def _cap_hits(self, match_pages: int, prompt_len: int) -> int:
@@ -1094,10 +1155,11 @@ class ContinuousBatcher:
         under pressure), and start its prefill cursor past the hits.
         Preempted requests re-admit through `_admit_resume` instead.
         Admission is gated by `HostPageAllocator.available_after_adopt`.
-        Returns True when page tables changed (device sync required).
-        DESIGN.md §7."""
+        Returns the requests admitted; page tables changed (device sync
+        required) when there are any. Each adds its wait since it was
+        (re)queued to the counters. DESIGN.md §7."""
         ps = self.page_size
-        changed = False
+        admitted = []
         for i in range(self.batch):
             if self.rows[i] is not None or not self.queue:
                 continue
@@ -1106,7 +1168,7 @@ class ContinuousBatcher:
             if cand.uid in self._suspended:
                 if not self._admit_resume(i, k, cand):
                     break                        # wait for releases
-                changed = True
+                admitted.append(cand)
                 continue
             S = len(cand.prompt)                 # true length — no padding
             nb = S // ps                         # hashable full pages
@@ -1115,8 +1177,10 @@ class ContinuousBatcher:
                 toks, chain = self._admit_memo[cand.uid]
             else:
                 toks = np.asarray(cand.prompt, np.int32)
-                chain = (PG.chain_hashes(toks[:nb * ps], ps)
-                         if self.prefix_cache else [])
+                chain = []
+                if self.prefix_cache:
+                    with self.spans.span("cache.hash", pages=nb):
+                        chain = PG.chain_hashes(toks[:nb * ps], ps)
                 self._admit_memo[cand.uid] = (toks, chain)
             # host-tier prefetch at hash-match time (DESIGN.md §11): start
             # swap-in copies for the chain's hosted continuation; while
@@ -1148,10 +1212,13 @@ class ContinuousBatcher:
             self.prefilling[i] = {"toks": toks, "cursor": hit_toks, "S": S}
             self.pos[i] = hit_toks
             self.tok[i, 0] = 0
-            changed = True
-        if changed:
+            admitted.append(cand)
+        if admitted:
             self._progressed = True
-        return changed
+            now = time.perf_counter()
+            self.admissions += len(admitted)
+            self.queue_wait_s += sum(now - r._queued_at for r in admitted)
+        return admitted
 
     def _admit_resume(self, i: int, k: int, cand: Request) -> bool:
         """Re-admit a preempted request into row ``i`` (DESIGN.md §8).
@@ -1249,20 +1316,24 @@ class ContinuousBatcher:
         self.preempt_recompute_resumes += 1
         return True
 
-    def _chunk_prefill_fn(self, max_start: int):
-        """Jitted chunk fn for a dispatch whose deepest cursor is
-        ``max_start`` tokens: the static history-walk bound is the cursor
-        in blocks rounded up to a power of two (compile set stays
-        O(log max_blocks); masking trims the over-approximation), so a
-        chunk never materializes max_len of history (DESIGN.md §7).
+    def _hist_blocks(self, max_start: int) -> int:
+        """Static history-walk bound of a chunk dispatch whose deepest
+        cursor is ``max_start`` tokens: the cursor in blocks rounded up to
+        a power of two (compile set stays O(log max_blocks); masking trims
+        the over-approximation), so a chunk never materializes max_len of
+        history (DESIGN.md §7)."""
+        blocks = -(-max_start // self.page_size)
+        return 0 if blocks == 0 else min(1 << (blocks - 1).bit_length(),
+                                         self.max_blocks)
+
+    def _chunk_prefill_fn(self, hb: int):
+        """Jitted chunk fn for a dispatch with history bound ``hb``
+        (`_hist_blocks`), compiled as the program ``jit_chunk_prefill``.
 
         Keyed on (bound, use_fused_prefill, kv_cache_dtype) — the toggle
         and dtype are read from the live config at every dispatch, so
         flipping either mid-process compiles the other attention path /
         pool format instead of serving a stale trace (DESIGN.md §9)."""
-        blocks = -(-max_start // self.page_size)
-        hb = 0 if blocks == 0 else min(1 << (blocks - 1).bit_length(),
-                                       self.max_blocks)
         fused = bool(getattr(self.config, "use_fused_prefill", True))
         key = (hb, fused, self.kv_cache_dtype)
         fn = self._chunk_prefill_fns.get(key)
@@ -1273,11 +1344,11 @@ class ContinuousBatcher:
             # page pool in place instead of copying every pool buffer per
             # chunk dispatch (the scatter in prefill_at would otherwise
             # clone ~MBs of quantized pages each tick)
-            fn = self._chunk_prefill_fns[key] = jax.jit(
+            fn = self._chunk_prefill_fns[key] = TR.program(
                 make_chunk_prefill_fn(self.cfg, hist_blocks=hb,
                                       use_fused=fused,
                                       kv_cache_dtype=self.kv_cache_dtype),
-                donate_argnums=(2,))
+                "chunk_prefill", donate_argnums=(2,))
         return fn
 
     def _chunk_width(self, rem: int) -> int:
@@ -1322,28 +1393,36 @@ class ContinuousBatcher:
                   for i, st in self.prefilling.items()}
         w = self._chunk_width(rem_of[head])
         group = [i for i in order if self._chunk_width(rem_of[i]) == w]
-        toks = np.zeros((self.batch, w), np.int32)
-        start = np.zeros((self.batch,), np.int32)
-        valid = np.zeros((self.batch,), np.int32)
-        mask = np.zeros((self.batch,), bool)
-        for i in group:
-            st = self.prefilling[i]
-            c = min(self.prefill_chunk_tokens, rem_of[i])
-            toks[i, :c] = st["toks"][st["cursor"]:st["cursor"] + c]
-            start[i] = st["cursor"]
-            valid[i] = c
-            mask[i] = True
-        logits, self.state = self._chunk_prefill_fn(int(start.max()))(
-            self.params, jnp.asarray(toks), self.state, jnp.asarray(start),
-            jnp.asarray(valid), jnp.asarray(mask))
+        hb = self._hist_blocks(max(self.prefilling[i]["cursor"]
+                                   for i in group))
+        with self.spans.span("sched.prefill", width=w, hist_blocks=hb,
+                             rows=len(group)):
+            toks = np.zeros((self.batch, w), np.int32)
+            start = np.zeros((self.batch,), np.int32)
+            valid = np.zeros((self.batch,), np.int32)
+            mask = np.zeros((self.batch,), bool)
+            for i in group:
+                st = self.prefilling[i]
+                c = min(self.prefill_chunk_tokens, rem_of[i])
+                toks[i, :c] = st["toks"][st["cursor"]:st["cursor"] + c]
+                start[i] = st["cursor"]
+                valid[i] = c
+                mask[i] = True
+            args = (self.params, jnp.asarray(toks), self.state,
+                    jnp.asarray(start), jnp.asarray(valid), jnp.asarray(mask))
+            with self.spans.span("dispatch.prefill"):
+                logits, self.state = self._chunk_prefill_fn(hb)(*args)
         self._progressed = True
         self.prefill_tokens_computed += int(valid.sum())
-        sampled = None
+        self.prefill_tokens_dispatched += toks.size
         # resumed rows (DESIGN.md §8) restore their pre-preemption pending
         # token instead of redrawing — they are not "finishing" rows
         finishing = [i for i in group
                      if rem_of[i] <= self.prefill_chunk_tokens
                      and i not in self._resume_tok]
+        if finishing:                    # token index 0 for finishing rows
+            with self.spans.span("wait.prefill"):
+                sampled = self._sample_rows(logits, finishing, offset=0)
         done: list[Request] = []
         for i in group:
             st = self.prefilling[i]
@@ -1360,13 +1439,11 @@ class ContinuousBatcher:
                 rtok = self._resume_tok.pop(i, None)
                 if rtok is not None:
                     # recompute-resume complete: the pending token was drawn
-                    # before preemption (first token already recorded) — do
+                    # before preemption (first token already delivered) — do
                     # not redraw, do not re-record TTFT (DESIGN.md §8)
                     del self.prefilling[i]
                     self.tok[i, 0] = rtok
                     continue
-                if sampled is None:      # token index 0 for finishing rows
-                    sampled = self._sample_rows(logits, finishing, offset=0)
                 del self.prefilling[i]
                 r = self.rows[i]
                 if int(sampled[i]) in self._stop_ids(r):
@@ -1376,8 +1453,9 @@ class ContinuousBatcher:
                     done.append(r)
                     self._release_row(i)
                     continue
+                # the first token: this tick's decode scan appends it to
+                # `generated`, and the tick's end delivers it
                 self.tok[i, 0] = sampled[i]
-                self._record_first_token(r)
         return done
 
     # -- tiered KV cache: demotion / promotion copies (DESIGN.md §11) ------
@@ -1663,6 +1741,7 @@ class ContinuousBatcher:
             if self._demote_chain(swap_chain):
                 self.preempt_by_swap += 1
         r._submit_tick = self.ticks  # aging clock restarts at preemption
+        r._queued_at = time.perf_counter()
         self.queue.append(r)
 
     def _ensure_decode_pages(self, active: list[int]
@@ -1762,14 +1841,19 @@ class ContinuousBatcher:
         if self.state is None:
             self.state = self._init_state(self.batch)
         self.allocator.tick()        # fault-injection clock + deferred drain
-        if self._admit_chunked():
+        with self.spans.span("sched.admit") as sp:
+            admitted = self._admit_chunked()
+            if admitted:
+                sp.set(uids=TR.uids(admitted))
+        if admitted:
             self._sync_device()      # hit pages + cursors live before use
         done = self._advance_prefill()   # first-draw-is-stop completions
         active = [i for i, r in enumerate(self.rows)
                   if r is not None and i not in self.prefilling]
         n = None
         if active and self.watermark is not None:
-            active, n, grew = self._ensure_decode_pages(active)
+            with self.spans.span("sched.grow"):
+                active, n, grew = self._ensure_decode_pages(active)
             if grew:
                 self._sync_device()  # new/changed tables live before decode
         if active:
@@ -1795,6 +1879,16 @@ class ContinuousBatcher:
         Prefix mode adds the
         `HostPageAllocator` counters (hits / misses / reclaims /
         cow_retargets) and the page hit rate.
+
+        The tick's cumulative counters (DESIGN.md §6): ``ticks``;
+        ``admissions`` and ``queue_wait_s``, their summed wait from
+        (re)queueing to admission; ``first_token_s``, the first tokens'
+        summed time from submission to delivery (the sum of the TTFTs the
+        percentiles are taken over), and ``first_token_scan_s``, the part
+        of it from the ticks' scan launches to their tokens' bookkeeping
+        (`_scan_s`);
+        ``prefill_tokens_dispatched``, batch x width over the chunk
+        dispatches, beside the valid ``prefill_tokens_computed``.
 
         With a host tier attached (DESIGN.md §11) the report splits
         device vs host bytes — ``device_bytes_live`` counts HBM-resident
@@ -1852,7 +1946,13 @@ class ContinuousBatcher:
                "preempt_recompute_resumes": self.preempt_recompute_resumes,
                "decode_stall_ticks": self.decode_stall_ticks,
                "prefill_tokens_computed": self.prefill_tokens_computed,
+               "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
                "decode_tokens_computed": self.decode_tokens_computed,
+               "ticks": self.ticks,
+               "admissions": self.admissions,
+               "queue_wait_s": self.queue_wait_s,
+               "first_token_s": sum(self._ttfts),
+               "first_token_scan_s": self.first_token_scan_s,
                **self.lifecycle_report()}
         if not isinstance(spec, str):
             rep["kv_cache_layer_dtypes"] = list(layer_dts)

@@ -249,16 +249,6 @@ def test_flat_decode_is_single_pallas_call():
     assert _pallas_grids(jaxpr) == [(B, Hkv, nb)]
 
 
-def test_dma_skip_ratio_metric():
-    assert QA.dma_skip_ratio(np.full(4, 256), 64, 256) == 0.0
-    assert QA.dma_skip_ratio(np.full(4, 64), 64, 256) == 0.75
-    # length 0 still revisits one block (the clamp floor)
-    assert QA.dma_skip_ratio(np.asarray([0, 256]), 64, 256) == \
-        pytest.approx(3 / 8)
-    # ring: absolute length beyond max_len clamps to max_len
-    assert QA.dma_skip_ratio(np.asarray([512, 300]), 64, 256) == 0.0
-
-
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
 def test_ops_dispatch_consistency(impl):
     x = jax.random.normal(jax.random.PRNGKey(6), (256, 128))

@@ -139,18 +139,6 @@ def test_fused_prefill_skip_dead_invisible():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
-def test_prefill_dma_skip_ratio_metric():
-    # no history axis: nothing to skip
-    assert QP.prefill_dma_skip_ratio([0, 64], 8, 0) == 0.0
-    # every row at the bound: every step streams
-    assert QP.prefill_dma_skip_ratio(np.full(4, 64), 8, 8) == 0.0
-    # live pages [1, 1, 4, 8] of 8 -> 1 - 14/32
-    assert QP.prefill_dma_skip_ratio([0, 8, 32, 64], 8, 8) == \
-        pytest.approx(1 - 14 / 32)
-    # cursor-0 rows still revisit one clamped page (the clamp floor)
-    assert QP.prefill_dma_skip_ratio([0, 0], 8, 4) == pytest.approx(0.75)
-
-
 def test_flash_prefill_skip_dead_invisible():
     """Satellite: the same clamp ported to the dense flash-prefill kernel
     (kernels/flash_fwd.py) — causally-dead kv blocks stop streaming, with
@@ -165,20 +153,6 @@ def test_flash_prefill_skip_dead_invisible():
     b = FF.flash_prefill(q, k, v, block_q=8, block_k=8, skip_dead=False,
                          interpret=True)
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_flash_prefill_dma_skip_ratio_metric():
-    from repro.kernels import flash_fwd as FF
-    assert FF.dma_skip_ratio(32, 32, causal=False, block_q=8, block_k=8) \
-        == 0.0
-    # square causal, bq == bk: strictly-upper blocks are dead -> (n-1)/2n
-    assert FF.dma_skip_ratio(32, 32, block_q=8, block_k=8) == \
-        pytest.approx(6 / 16)
-    # kv_offset shifts the frontier: 32 queries appended after 32 resident
-    # keys — the last q block sees all 8 kv blocks, earlier ones skip
-    # their causal future (3 + 2 + 1 + 0 of 32 steps)
-    assert FF.dma_skip_ratio(32, 64, kv_offset=32, block_q=8,
-                             block_k=8) == pytest.approx(6 / 32)
 
 
 def test_oracle_accepts_bf16_history():

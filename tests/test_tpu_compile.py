@@ -83,9 +83,12 @@ def test_paged_decode_compiles(one_chip, kv_dtype):
             q, kq, ks, vq, vs, table, lengths, interpret=False,
             kv_dtype=kv_dtype)
 
-    _compile(decode, spec((BATCH, KV_HEADS * GROUP, HEAD_DIM), jnp.bfloat16),
-             kq, ks, kq, ks, spec((BATCH, MAX_BLOCKS), jnp.int32),
-             spec((BATCH,), jnp.int32))
+    compiled = _compile(decode, spec((BATCH, KV_HEADS * GROUP, HEAD_DIM),
+                                     jnp.bfloat16),
+                        kq, ks, kq, ks, spec((BATCH, MAX_BLOCKS), jnp.int32),
+                        spec((BATCH,), jnp.int32))
+    # the kernel's name in a device trace, where the benchmark reads it
+    assert "%_paged_decode." in compiled.as_text()
 
 
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
@@ -99,10 +102,11 @@ def test_fused_prefill_compiles(one_chip, kv_dtype):
             hist_blocks=4, interpret=False, kv_dtype=kv_dtype)
 
     chunk = lambda heads: spec((BATCH, heads, CHUNK, HEAD_DIM), jnp.bfloat16)
-    _compile(prefill, chunk(KV_HEADS * GROUP), chunk(KV_HEADS),
-             chunk(KV_HEADS), kq, ks, kq, ks,
-             spec((BATCH, MAX_BLOCKS), jnp.int32), spec((BATCH,), jnp.int32),
-             spec((BATCH,), jnp.int32))
+    compiled = _compile(prefill, chunk(KV_HEADS * GROUP), chunk(KV_HEADS),
+                        chunk(KV_HEADS), kq, ks, kq, ks,
+                        spec((BATCH, MAX_BLOCKS), jnp.int32),
+                        spec((BATCH,), jnp.int32), spec((BATCH,), jnp.int32))
+    assert "%_paged_prefill." in compiled.as_text()
 
 
 def test_contiguous_decode_compiles(one_chip):
