@@ -19,6 +19,9 @@ Two device pytrees plus one host-side policy object:
     page_table  int32 (B, max_blocks)       physical page per logical block
     resid_k/v   ref_dtype (B, H_kv, page_size, D)  unquantized current page
     length      int32 (B,)                  per-row tokens written
+    layer       None, or int32 () on one layer's view of a layer-stacked
+                cache (`at_layer`): the pool stays stacked and is indexed
+                by it in place
 
 ``HostPageAllocator`` — the host-authoritative allocation policy
 (DESIGN.md §7): free list, per-page refcounts, the content-hash index that
@@ -554,20 +557,24 @@ def scatter_to_pool(k_q, k_s, v_q, v_s):
     return (pad(q2p(k_q)), pad(s2p(k_s)), pad(q2p(v_q)), pad(s2p(v_s)), table)
 
 
-def gather_pages(pool_kq, pool_ks, pool_vq, pool_vs, page_table):
+def gather_pages(pool_kq, pool_ks, pool_vq, pool_vs, page_table,
+                 layer=None):
     """Materialize the contiguous cache layout from a page pool:
     pool int8 (n_pages, H, ps, D) + table (B, NT) -> int8 (B, H, NT*ps, D)
-    + f32 scales (B, H, NT, D). Reference path — the fused kernel gathers
-    pages via its index_map instead (DESIGN.md §5)."""
+    + f32 scales (B, H, NT, D). With ``layer`` the pools are layer-stacked
+    (L, n_pages, H, ps, D) and one gather reads that layer's pages straight
+    from the stack. Reference path — the fused kernel gathers pages via its
+    index_map instead (DESIGN.md §5)."""
     B, NT = page_table.shape
-    _, H, ps, D = pool_kq.shape
+    H, ps, D = pool_kq.shape[-3:]
+    idx = page_table if layer is None else (layer, page_table)
 
     def gq(pool_q):
-        g = pool_q[page_table]                       # (B, NT, H, ps, D)
+        g = pool_q[idx]                              # (B, NT, H, ps, D)
         return g.transpose(0, 2, 1, 3, 4).reshape(B, H, NT * ps, D)
 
     def gs(pool_s):                                  # (B, NT, H, 1, D)
-        return pool_s[page_table][:, :, :, 0].transpose(0, 2, 1, 3)
+        return pool_s[idx][:, :, :, 0].transpose(0, 2, 1, 3)
 
     return gq(pool_kq), gs(pool_ks), gq(pool_vq), gs(pool_vs)
 
@@ -674,7 +681,8 @@ class PagePool:
 
 
 @partial(jax.tree_util.register_dataclass,
-         data_fields=["pool", "page_table", "resid_k", "resid_v", "length"],
+         data_fields=["pool", "page_table", "resid_k", "resid_v", "length",
+                      "layer"],
          meta_fields=[])
 @dataclasses.dataclass
 class PagedQuantizedKVCache:
@@ -688,12 +696,20 @@ class PagedQuantizedKVCache:
     two behind one code path; granularity is always per_block with
     block_size == page_size. `prefill_at` / `fork_row` are the chunked-
     prefill and sharing entry points of DESIGN.md §7.
+
+    A serving state stacks the caches of a layer group along a leading
+    (L,) axis. Decode never slices a layer's pool out of that stack:
+    `at_layer` gives the layer's view (its own table, residual and length
+    over the whole stacked pool, with ``layer`` set), `append` scatters a
+    flushed page at ``[layer, page]`` and the decode kernel reads pages at
+    ``[layer, page]``, and `put_layer` writes the small leaves back.
     """
     pool: PagePool
     page_table: jax.Array   # int32 (B, max_blocks); SENTINEL_PAGE = unmapped
     resid_k: jax.Array      # ref_dtype (B, H_kv, page_size, D)
     resid_v: jax.Array
     length: jax.Array       # int32 (B,) per-row tokens written
+    layer: jax.Array | None = None   # int32 (): set on a layer view only
 
     # -- constructors ------------------------------------------------------
     @staticmethod
@@ -896,6 +912,34 @@ class PagedQuantizedKVCache:
             resid_v=self.resid_v.at[dst].set(self.resid_v[src]),
             length=self.length.at[dst].set(self.length[src]))
 
+    # -- layer-stacked state (decode) --------------------------------------
+    def at_layer(self, layer: jax.Array) -> "PagedQuantizedKVCache":
+        """Layer ``layer``'s view of a layer-stacked cache (every leaf with
+        a leading (L,) axis): that layer's page table, residual and length
+        over the whole stacked pool, which `append` and the decode kernel
+        index by ``layer`` in place. Decode's append and attention read
+        are a view's only users; `put_layer` writes it back."""
+        take = lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0,
+                                                      keepdims=False)
+        return dataclasses.replace(
+            self, page_table=take(self.page_table),
+            resid_k=take(self.resid_k), resid_v=take(self.resid_v),
+            length=take(self.length), layer=layer)
+
+    def put_layer(self, view: "PagedQuantizedKVCache"
+                  ) -> "PagedQuantizedKVCache":
+        """Write a layer view (`at_layer`) back into this stacked cache: its
+        page table, residual and length at ``view.layer``, and its pool,
+        which the view's flush already updated in place."""
+        put = lambda a, v: jax.lax.dynamic_update_index_in_dim(
+            a, v, view.layer, 0)
+        return dataclasses.replace(
+            self, pool=view.pool,
+            page_table=put(self.page_table, view.page_table),
+            resid_k=put(self.resid_k, view.resid_k),
+            resid_v=put(self.resid_v, view.resid_v),
+            length=put(self.length, view.length))
+
     # -- decode append -----------------------------------------------------
     def append(self, k: jax.Array, v: jax.Array,
                row_mask: jax.Array | None = None) -> "PagedQuantizedKVCache":
@@ -907,7 +951,8 @@ class PagedQuantizedKVCache:
         position). Rows whose current block is unmapped flush to the
         sentinel page. `row_mask` (B,) bool freezes unmasked rows entirely
         (the scheduler masks out empty/finished rows so their lengths stay
-        exactly 0 between requests).
+        exactly 0 between requests). On a layer view (`at_layer`) the page
+        lands at ``[layer, page]`` of the stacked pool, in place.
         """
         B, H, _, D = k.shape
         ps = self.page_size
@@ -928,14 +973,15 @@ class PagedQuantizedKVCache:
         fq_v, fs_v = Q.quantize_page_matrix(resid_v, kv_dtype)
         pid = self.page_table[jnp.arange(B), blk]   # (B,)
         pid = jnp.where(full, pid, SENTINEL_PAGE)   # non-flushing -> sentinel
+        at = pid if self.layer is None else (self.layer, pid)
         # the residual is already heads-major: a flushed page lands as is
         srow = lambda s: s.astype(jnp.float32)[:, :, None]   # (B, H, 1, D)
         pool = dataclasses.replace(
             self.pool,
-            k_q=self.pool.k_q.at[pid].set(fq_k),
-            v_q=self.pool.v_q.at[pid].set(fq_v),
-            k_s=self.pool.k_s.at[pid].set(srow(fs_k)),
-            v_s=self.pool.v_s.at[pid].set(srow(fs_v)))
+            k_q=self.pool.k_q.at[at].set(fq_k),
+            v_q=self.pool.v_q.at[at].set(fq_v),
+            k_s=self.pool.k_s.at[at].set(srow(fs_k)),
+            v_s=self.pool.v_s.at[at].set(srow(fs_v)))
         clear = full[:, None, None, None]
         advance = 1 if row_mask is None else row_mask.astype(jnp.int32)
         return dataclasses.replace(

@@ -123,7 +123,7 @@ def quant_attention_decode_partials(q, k_q, k_s, v_q, v_s, length, *,
 
 def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
                                     page_table, lengths, *,
-                                    kv_dtype: str = "int8",
+                                    kv_dtype: str = "int8", layer=None,
                                     impl: Impl = "auto"):
     """Flash partials over a quantized page pool through per-row page tables.
 
@@ -133,14 +133,16 @@ def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
     page_table (B, NT) int32; lengths (B,) int32 — per-row valid tokens
     (pass the flushed prefix count; the residual tail merges separately).
     Lengths also bound each row's page walk: the kernel never streams pages
-    (or reads table entries) past ceil(length / ps).
+    (or reads table entries) past ceil(length / ps). ``layer`` (int32
+    scalar) reads layer ``layer`` of a layer-stacked pool, every pool array
+    with a leading (L,) axis, in place (DESIGN.md §5).
     Returns (o_unnormalized (B, H, D), m (B, H, 1), l (B, H, 1)).
     """
     impl = resolve_impl(impl)
     if impl == "xla":
         from repro.core.paging import gather_pages
         k_q, k_s, v_q, v_s = gather_pages(
-            pool_kq, pool_ks, pool_vq, pool_vs, page_table)
+            pool_kq, pool_ks, pool_vq, pool_vs, page_table, layer)
         if kv_dtype == "int4":
             # gathered packed bytes concatenate page-contiguously, so one
             # unpack of the token axis restores logical token order
@@ -149,7 +151,8 @@ def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
         return _decode_partials_xla(q, k_q, k_s, v_q, v_s, lengths, None)
     return _qa.paged_attention_decode_partials(
         q, pool_kq, pool_ks, pool_vq, pool_vs, page_table, lengths,
-        kv_dtype=kv_dtype, interpret=impl == "pallas_interpret")
+        kv_dtype=kv_dtype, layer=layer,
+        interpret=impl == "pallas_interpret")
 
 
 def paged_attention_decode(q, pool_kq, pool_ks, pool_vq, pool_vs, page_table,

@@ -386,8 +386,8 @@ def page_dequant(q_tile, scale_row, kv_dtype: str, page_size: int):
     return q_tile.astype(jnp.float32) * scale_row.astype(jnp.float32)
 
 
-def _paged_decode_kernel(pt_ref, len_ref, q_ref, kq_ref, ks_ref, vq_ref,
-                         vs_ref, o_ref, m_ref, l_ref,
+def _paged_decode_kernel(pt_ref, len_ref, layer_ref, q_ref, kq_ref, ks_ref,
+                         vq_ref, vs_ref, o_ref, m_ref, l_ref,
                          m_scr, l_scr, acc_scr, *, page_size: int,
                          kv_dtype: str):
     b = pl.program_id(0)
@@ -420,14 +420,15 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, kq_ref, ks_ref, vq_ref,
 @functools.partial(jax.jit, static_argnames=("skip_dead", "interpret",
                                              "kv_dtype"))
 def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
-                  lengths, *, skip_dead: bool, interpret: bool,
+                  lengths, layer, *, skip_dead: bool, interpret: bool,
                   kv_dtype: str):
-    """qg (B, Hkv, Gp, D); pool_* (P, Hkv, ps_packed, D) in the pool's
+    """qg (B, Hkv, Gp, D); pool_* (L, P, Hkv, ps_packed, D) in the pool's
     storage dtype (int8 / fp8_e4m3 / int4-packed: ps_packed = ps // 2) /
-    (P, Hkv, 1, D) f32 scales; page_table (B, NT) int32; lengths (B,) int32.
+    (L, P, Hkv, 1, D) f32 scales, a layer-stacked pool read at ``layer``
+    (1,) int32; page_table (B, NT) int32; lengths (B,) int32.
     Returns (o (B, Hkv, Gp, D), m (B, Hkv, Gp, 1), l (B, Hkv, Gp, 1))."""
     B, Hkv, Gp, D = qg.shape
-    ps_eff = pool_kq.shape[2]            # packed token rows per page
+    ps_eff = pool_kq.shape[3]            # packed token rows per page
     ps = 2 * ps_eff if kv_dtype == "int4" else ps_eff   # logical tokens
     NT = page_table.shape[1]
     if skip_dead:
@@ -437,26 +438,30 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
         t_idx = lambda t, ln: _dead_clamp(t, ln, ps, NT * ps)
     else:
         t_idx = lambda t, ln: t
-    page = lambda b, h, t, pt, ln: (pt[b, t_idx(t, ln[b])], h, 0, 0)
+    # the stacked pool is one operand, whole: the layer is an index of the
+    # DMA, so no layer's pool is ever sliced out into a buffer of its own
+    page = lambda b, h, t, pt, ln, ly: (ly[0], pt[b, t_idx(t, ln[b])], h,
+                                        0, 0)
+    row = lambda b, h, t, pt, ln, ly: (b, h, 0, 0)
     kernel = functools.partial(_paged_decode_kernel, page_size=ps,
                                kv_dtype=kv_dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,               # page table + lengths in SMEM
+        num_scalar_prefetch=3,          # page table, lengths, layer in SMEM
         grid=(B, Hkv, NT),
         in_specs=[
-            pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, pt, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Gp, D), row),
             # physical page gather: logical block t of row b -> pt[b, t];
             # heads-major pages make each block's last two dims the
             # array's own (ps_packed, D) and (1, D), as Mosaic requires
-            pl.BlockSpec((1, 1, ps_eff, D), page),
-            pl.BlockSpec((1, 1, 1, D), page),
-            pl.BlockSpec((1, 1, ps_eff, D), page),
-            pl.BlockSpec((1, 1, 1, D), page),
+            pl.BlockSpec((None, 1, 1, ps_eff, D), page),
+            pl.BlockSpec((None, 1, 1, 1, D), page),
+            pl.BlockSpec((None, 1, 1, ps_eff, D), page),
+            pl.BlockSpec((None, 1, 1, 1, D), page),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, Gp, D), lambda b, h, t, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Gp, 1), lambda b, h, t, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, Gp, 1), lambda b, h, t, pt, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, Gp, D), row),
+            pl.BlockSpec((1, 1, Gp, 1), row),
+            pl.BlockSpec((1, 1, Gp, 1), row),
         ],
         scratch_shapes=[
             pltpu.VMEM((Gp, 1), jnp.float32),
@@ -472,27 +477,34 @@ def _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs, page_table,
                    jax.ShapeDtypeStruct((B, Hkv, Gp, 1), jnp.float32)],
         interpret=interpret,
         name="_paged_decode",        # the kernel's name in a device trace
-    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
+    )(page_table.astype(jnp.int32), lengths.astype(jnp.int32), layer,
       qg, pool_kq, pool_ks, pool_vq, pool_vs)
 
 
 def paged_attention_decode_partials(q, pool_kq, pool_ks, pool_vq, pool_vs,
                                     page_table, lengths, *, interpret: bool,
                                     skip_dead: bool = True,
-                                    kv_dtype: str = "int8"):
+                                    kv_dtype: str = "int8", layer=None):
     """Batched paged decode partials: q (B, H, D) over a page pool
     (P, Hkv, ps_packed, D) in ``kv_dtype`` storage (int8 / fp8_e4m3 /
     int4-packed — DESIGN.md §9) through per-row page tables (B, NT).
-    `lengths` (B,) masks each row independently (pass the *flushed* prefix
-    count; the fp residual tail is merged by the caller) and bounds each
-    row's page walk (`skip_dead`). Returns (o_unnormalized (B, H, D),
-    m (B, H, 1), l (B, H, 1))."""
+    With ``layer`` (an int32 scalar) the pool arrays are layer-stacked,
+    (L, P, Hkv, ps_packed, D), and layer ``layer``'s pages are read in
+    place (DESIGN.md §5). `lengths` (B,) masks each row independently
+    (pass the *flushed* prefix count; the fp residual tail is merged by
+    the caller) and bounds each row's page walk (`skip_dead`). Returns
+    (o_unnormalized (B, H, D), m (B, H, 1), l (B, H, 1))."""
     B, H, D = q.shape
-    Hkv = pool_kq.shape[1]
+    if layer is None:           # one layer's pool: a stack of one
+        pool_kq, pool_ks, pool_vq, pool_vs = (
+            a[None] for a in (pool_kq, pool_ks, pool_vq, pool_vs))
+        layer = 0
+    Hkv = pool_kq.shape[2]
     qg, G = _group_queries(q, Hkv)
     lengths = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     o, m, l = _paged_decode(qg, pool_kq, pool_ks, pool_vq, pool_vs,
-                            page_table, lengths, skip_dead=skip_dead,
+                            page_table, lengths, layer, skip_dead=skip_dead,
                             interpret=interpret, kv_dtype=kv_dtype)
     trim = lambda a: a[:, :, :G].reshape(B, H, a.shape[-1])
     return trim(o), trim(m), trim(l)

@@ -345,7 +345,8 @@ def _decode_paged(q, cache: PG.PagedQuantizedKVCache, *, impl="auto"):
     n_tail = cache.length % ps
     o1, m1, l1 = ops.paged_attention_decode_partials(
         q, cache.pool.k_q, cache.pool.k_s, cache.pool.v_q, cache.pool.v_s,
-        cache.page_table, flushed, kv_dtype=cache.pool.kv_dtype, impl=impl)
+        cache.page_table, flushed, kv_dtype=cache.pool.kv_dtype,
+        layer=cache.layer, impl=impl)
     m2, l2, o2 = _decode_partials_fp(q, cache.resid_k, cache.resid_v, n_tail)
     return _merge_partials(o1, m1, l1, o2, m2, l2)
 

@@ -353,6 +353,26 @@ def _serve(params, tok, cfg: ModelConfig, state, positions, mode: str,
                 for k in caches:
                     new_caches[k].append(nc[k])
             new_state.update(new_caches)
+        elif mode == "decode" and all(
+                isinstance(c, PG.PagedQuantizedKVCache)
+                for c in caches.values()):
+            # Paged decode (DESIGN.md §5): the stacked caches ride the
+            # scan's carry, not its xs/ys, so the page pools are read by
+            # the kernel and written by the flush scatter in place, indexed
+            # by layer; only each layer's table, residual and length are
+            # sliced out and written back.
+            def carried_body(carry, gparams_and_layer):
+                x, caches = carry
+                gparams, layer = gparams_and_layer
+                x, views = group_body(
+                    x, (gparams, {k: c.at_layer(layer)
+                                  for k, c in caches.items()}))
+                return (x, {k: caches[k].put_layer(v)
+                            for k, v in views.items()}), None
+            (x, new_caches), _ = jax.lax.scan(
+                carried_body, (x, caches),
+                (gp, jnp.arange(n_groups, dtype=jnp.int32)))
+            new_state.update(new_caches)
         else:
             x, new_caches = jax.lax.scan(group_body, x, (gp, caches))
             new_state.update(new_caches)

@@ -374,7 +374,10 @@ class ContinuousBatcher:
             cfg, max_len=max_len, paged=paged, n_pages=n_pages,
             kv_cache_dtype=self.kv_cache_dtype)
         self._prefill = jax.jit(prefill)
-        self._decode = TR.program(decode, "decode_fn")
+        # the state is donated to both decode programs, as to chunk
+        # prefill: the caller replaces self.state with the result in the
+        # same statement, and the pools are then updated in place
+        self._decode = TR.program(decode, "decode_fn", donate_argnums=(2,))
         self._init_state = init_state
         self.queue: deque[Request] = deque()
         self.rows: list[Request | None] = [None] * batch
@@ -746,7 +749,7 @@ class ContinuousBatcher:
             self.cfg, max_len=self.max_len, paged=True,
             n_pages=self.n_pages, kv_cache_dtype=want)
         self._prefill = jax.jit(prefill)
-        self._decode = TR.program(decode, "decode_fn")
+        self._decode = TR.program(decode, "decode_fn", donate_argnums=(2,))
         self._init_state = init_state
         self.state = None                      # rebuilt lazily next tick
         # indexed/cached pages hold bytes in the OLD format — a fresh
@@ -868,7 +871,8 @@ class ContinuousBatcher:
             def run(params, tok, state, pos, row_mask, sampling):
                 return T.decode_scan(params, tok, cfg, state, pos, steps=n,
                                      row_mask=row_mask, sampling=sampling)
-            fn = self._chunk_fns[key] = TR.program(run, "run")
+            fn = self._chunk_fns[key] = TR.program(run, "run",
+                                                   donate_argnums=(2,))
         return fn
 
     def _finish_chunk(self, active: list[int], toks: np.ndarray,

@@ -221,6 +221,27 @@ def test_paged_decode_ragged_lengths_and_bounded_walk():
     np.testing.assert_array_equal(np.asarray(l), np.asarray(l2))
 
 
+@pytest.mark.parametrize("impl", ["pallas_interpret", "xla"])
+def test_paged_decode_reads_a_stacked_pool_at_its_layer(impl):
+    """A layer-stacked pool read at layer 1 gives bit for bit what that
+    layer's own pool gives; the other layers' pages are never read."""
+    from repro.core.paging import scatter_to_pool
+    q, kq, kss, vq, vs = _quantized_cache()
+    pools = scatter_to_pool(kq, kss, vq, vs)
+    table = pools[-1]
+    flushed = jnp.asarray([0, 64, 128, 256], jnp.int32)
+    stacked = [jnp.stack([-a, a, a * 2]) for a in pools[:4]]
+    one = ops.paged_attention_decode_partials(q, *pools[:4], table, flushed,
+                                              impl=impl)
+    at = ops.paged_attention_decode_partials(q, *stacked, table, flushed,
+                                             layer=jnp.int32(1), impl=impl)
+    for x, y in zip(one, at):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    other = ops.paged_attention_decode_partials(
+        q, *stacked, table, flushed, layer=jnp.int32(2), impl=impl)
+    assert not np.array_equal(np.asarray(other[0]), np.asarray(one[0]))
+
+
 def _pallas_grids(closed_jaxpr):
     """Grid of every pallas_call in a traced function, nested jaxprs
     included."""

@@ -5,8 +5,9 @@ told about (`jax.experimental.topologies`), at internlm2_1_8b widths
 (8 KV heads x 128, GQA group 2, page 256, a 64-page pool, batch 8,
 max_len 2048). A kernel whose blocks Mosaic refuses — a tile that is not
 (8, 128)-aligned, a shift it cannot legalize, a VMEM overflow — fails
-here instead of on the chip. Nothing runs, so these tests say nothing
-about results or times.
+here instead of on the chip. The decode scan program is compiled whole
+too, and its memory analysis shows whether the page pools are updated in
+place. Nothing runs, so these tests say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -23,7 +24,7 @@ from repro.core import quantization as Q
 from repro.kernels import quant_attention as QA
 from repro.kernels import quant_prefill as QP
 
-KV_HEADS, GROUP, HEAD_DIM, PAGE = 8, 2, 128, 256
+KV_HEADS, GROUP, HEAD_DIM, PAGE, LAYERS = 8, 2, 128, 256, 24
 BATCH, MAX_BLOCKS, POOL_PAGES = 8, 8, 64
 CHUNK = 4 * PAGE                  # the scheduler's default prefill chunk
 KV_DTYPES = ["int8", "fp8_e4m3", "int4"]
@@ -59,12 +60,13 @@ def _spec(sharding):
                                                      sharding=sharding)
 
 
-def _pool(spec, kv_dtype):
-    """(values, scales) shapes of one heads-major page pool."""
+def _pool(spec, kv_dtype, layers=()):
+    """(values, scales) shapes of one heads-major page pool, or of a stack
+    of them when ``layers`` is (L,)."""
     packed = Q.packed_tokens(PAGE, kv_dtype)
-    return (spec((POOL_PAGES, KV_HEADS, packed, HEAD_DIM),
+    return (spec((*layers, POOL_PAGES, KV_HEADS, packed, HEAD_DIM),
                  Q.kv_storage_dtype(kv_dtype)),
-            spec((POOL_PAGES, KV_HEADS, 1, HEAD_DIM), jnp.float32))
+            spec((*layers, POOL_PAGES, KV_HEADS, 1, HEAD_DIM), jnp.float32))
 
 
 def _compile(fn, *shapes):
@@ -73,20 +75,22 @@ def _compile(fn, *shapes):
     return compiled
 
 
+@pytest.mark.parametrize("stacked", [False, True])
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES)
-def test_paged_decode_compiles(one_chip, kv_dtype):
+def test_paged_decode_compiles(one_chip, kv_dtype, stacked):
+    """One layer's pool, and a 24-layer stack read at a layer index."""
     spec = _spec(one_chip)
-    kq, ks = _pool(spec, kv_dtype)
+    kq, ks = _pool(spec, kv_dtype, (LAYERS,) if stacked else ())
 
-    def decode(q, kq, ks, vq, vs, table, lengths):
+    def decode(q, kq, ks, vq, vs, table, lengths, layer):
         return QA.paged_attention_decode_partials(
             q, kq, ks, vq, vs, table, lengths, interpret=False,
-            kv_dtype=kv_dtype)
+            kv_dtype=kv_dtype, layer=layer if stacked else None)
 
     compiled = _compile(decode, spec((BATCH, KV_HEADS * GROUP, HEAD_DIM),
                                      jnp.bfloat16),
                         kq, ks, kq, ks, spec((BATCH, MAX_BLOCKS), jnp.int32),
-                        spec((BATCH,), jnp.int32))
+                        spec((BATCH,), jnp.int32), spec((), jnp.int32))
     # the kernel's name in a device trace, where the benchmark reads it
     assert "%_paged_decode." in compiled.as_text()
 
@@ -121,3 +125,45 @@ def test_contiguous_decode_compiles(one_chip):
 
     _compile(decode, spec((BATCH, KV_HEADS * GROUP, HEAD_DIM), jnp.bfloat16),
              vals, scales, vals, scales, spec((BATCH,), jnp.int32))
+
+
+def test_decode_scan_updates_the_donated_pool_in_place(one_chip):
+    """The scheduler's decode scan (`jit_run`) for internlm2_1_8b at this
+    file's sizes: the donated state's buffers are reused for the result
+    (aliased bytes cover every layer's pool), and the program's own
+    temporaries stay below one layer's pool, so no pool is copied.
+
+    Four of the model's 24 layers: at 24, XLA also copies the stacked
+    attention weights into another layout once per call (0.3 GB of
+    temporaries that have nothing to do with the pool)."""
+    import dataclasses
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import transformer as T
+    from repro.serving import ContinuousBatcher, EngineConfig
+    cfg = dataclasses.replace(get_config("internlm2_1_8b"), n_layers=4)
+    spec = _spec(one_chip)
+    on_chip = lambda tree: jax.tree.map(
+        lambda a: spec(a.shape, a.dtype), tree)
+    params = jax.eval_shape(lambda: T.init_params(cfg, jax.random.PRNGKey(0)))
+    b = ContinuousBatcher(params, cfg, EngineConfig(
+        batch=BATCH, max_len=MAX_BLOCKS * PAGE, paged=True,
+        n_pages=POOL_PAGES))
+    state = jax.eval_shape(lambda: b._init_state(BATCH))
+    pool = state["p0"].pool
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in (pool.k_q, pool.v_q, pool.k_s, pool.v_s))
+    assert pool.k_q.shape[0] == cfg.n_layers
+    i32 = lambda *shape: spec(shape, jnp.int32)
+    auto = ops.resolve_impl
+    with pytest.MonkeyPatch.context() as mp:      # select the Pallas kernels
+        mp.setattr(ops, "resolve_impl",
+                   lambda impl="auto": "pallas" if impl == "auto"
+                   else auto(impl))
+        compiled = b._chunk_fn(8).lower(
+            on_chip(params), i32(BATCH, 1), on_chip(state), i32(BATCH),
+            spec((BATCH,), jnp.bool_), None).compile()
+    assert "%_paged_decode." in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // cfg.n_layers
