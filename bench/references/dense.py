@@ -11,10 +11,11 @@ the program hold equal weights without either handing them over.
 The forward pass is straightforward `jax.numpy` in float32 under
 ``default_matmul_precision("highest")``: pre-norm RMSNorm, GQA attention
 with optional QKV bias and half-split RoPE, SwiGLU MLP, an untied output
-head over the configuration's vocabulary rows. No cache, no kernels, no
-quantization. It runs layer by layer over a batch of sequences, drawing
-each layer's weights only while it is applied, so that a model that fills
-the chip in bf16 fits here in float32.
+head over the configuration's vocabulary rows; every layer has full
+attention and a dense MLP, and `check` refuses any other. No cache, no
+kernels, no quantization. It runs layer by layer over a batch of
+sequences, drawing each layer's weights only while it is applied, so that
+a model that fills the chip in bf16 fits here in float32.
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
+from bench.spec import Refused
 
 QUERY_BLOCK = 512
 PAD_TO = 1024
@@ -125,12 +127,22 @@ def arch_items(arch: dict) -> tuple:
     return tuple((k, arch[k]) for k in keep)
 
 
+def check(arch: dict):
+    """Refuses an `arch` with a layer this reference does not model
+    (experts, a sliding window) instead of computing a dense model."""
+    other = sorted(set(arch["layer_kinds"]) - {("full", "dense")})
+    if other:
+        raise Refused(f"the dense reference models full attention and a "
+                      f"dense MLP in every layer, not {other}")
+
+
 def served_gaps(arch: dict, seed: int, sequences: list[tuple]) -> list:
     """For each (prompt, served) pair of int arrays, the float32 reference's
     logits at the positions that produced the served tokens, reduced to
     one gap per served token: how far the served token's logit lies below
     the reference's best there (0 where they agree). Returns one float32
     array per sequence."""
+    check(arch)
     items = arch_items(arch)
     keys = jax.random.split(weights.base_key(seed), arch["layers"] + 3)
     root = weights.bias_root(seed)

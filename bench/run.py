@@ -185,21 +185,36 @@ class Driver:
 def make_params(cfg, seed: int):
     """The program's weights from the seed, in one jitted call on the
     device, in the type they are served in."""
+    import functools
+
     import jax
     import jax.numpy as jnp
     from bench import weights
     from repro.models import transformer
 
+    def biases(bias_root, attn, layer):
+        """The benchmark's draw for `layer`, in the type `attn` holds."""
+        drawn = weights.qkv_biases(bias_root, layer, attn["bq"].shape[-1],
+                                   attn["bk"].shape[-1])
+        return {k: v.astype(attn[k].dtype) for k, v in drawn.items()}
+
     def init(key, bias_root):
         p = transformer.init_params(cfg, key)
-        if cfg.qkv_bias:         # the initializer's are zero: draw them
-            assert set(p["blocks"]) == {"p0"} and not p["tail"]
-            attn = p["blocks"]["p0"]["attn"]
-            drawn = jax.vmap(lambda layer: weights.qkv_biases(
-                bias_root, layer, attn["bq"].shape[-1],
-                attn["bk"].shape[-1]))(jnp.arange(cfg.n_layers))
-            attn.update({k: v.astype(attn[k].dtype)
-                         for k, v in drawn.items()})
+        if not cfg.qkv_bias:
+            return p
+        # the initializer's biases are zero: draw them into every layer
+        # that has attention, by the layer's index in the whole stack
+        period = len(cfg.block_pattern)
+        stacked = cfg.n_layers // period * period
+        for i in range(period):
+            attn = p["blocks"][f"p{i}"].get("attn")
+            if attn is not None:
+                attn.update(jax.vmap(functools.partial(
+                    biases, bias_root, attn))(jnp.arange(i, stacked, period)))
+        for j, block in enumerate(p["tail"]):
+            if "attn" in block:
+                block["attn"].update(
+                    biases(bias_root, block["attn"], stacked + j))
         return p
 
     params = jax.jit(init)(weights.base_key(seed), weights.bias_root(seed))
@@ -277,6 +292,7 @@ def run(args, *, require_chip: bool = True, workload: dict | None = None,
     w = workload or spec.workload(args.workload)
     cfg_file = config or spec.config(w["config"])
     arch = spec.arch_of(cfg_file)
+    spec.reference(cfg_file).check(arch)
     if kv_dtype:                      # the control: a lower-precision pool
         arch["kv_dtype"] = kv_dtype
     mix = traffic_gen.load(w["traffic"]) if isinstance(w["traffic"], str) \
@@ -365,7 +381,8 @@ def run(args, *, require_chip: bool = True, workload: dict | None = None,
         if v is not None:
             metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
-    n_ttft = len(submitted)
+    n_ttft = sum(1 for t in turns
+                 if t.deliveries and t0 < t.deliveries[0][0] <= t1)
     log(f"window {t1 - t0:.3f}s: {len(submitted)} turns submitted, "
         f"{len(finished)} finished, {sum(1 for t in turns if len(t.deliveries) > 1 and t.deliveries[-1][0] > t0)}"
         f" turns delivering; ttft samples {n_ttft}; {in_window_compiles} "

@@ -42,8 +42,8 @@ def test_configs_name_their_reference_and_mix_its_generator():
     from bench import spec, traffic_gen
     for cell in spec.benchmark()["workloads"]:
         w = spec.workload(cell["name"])
-        assert hasattr(spec.reference(spec.config(w["config"])),
-                       "served_gaps")
+        ref = spec.reference(spec.config(w["config"]))
+        assert callable(ref.served_gaps) and callable(ref.check)
         mix = traffic_gen.load(w["traffic"])
         tr = traffic_gen.make(mix, vocab=1000, max_len=w["max_len"],
                               clients=2, page=256, seed=1)
